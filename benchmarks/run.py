@@ -26,6 +26,8 @@ import os
 import sys
 import time
 
+from repro.jax_cache import use_compile_cache
+
 from . import (contention, factor_analysis, feature_size,
                hardware_counters, memory, roofline_table, scan, shard,
                traverse_bench, ycsb)
@@ -108,6 +110,8 @@ def main(argv=None):
                                                        "stacked"),
                     help="descent layout (default: tree config)")
     args = ap.parse_args(argv)
+    use_compile_cache(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
     only = args.suite or args.only
     names = only.split(",") if only else list(SUITES)
     os.makedirs(args.out, exist_ok=True)

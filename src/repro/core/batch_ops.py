@@ -28,11 +28,10 @@ import jax.numpy as jnp
 from repro import obs
 
 from .branch import BranchStats
-from .fbtree import (BIG, EMPTY, FBTree, Level, TreeArrays,
-                     _device_build_from_sorted, chunk_of_pos, chunk_start,
-                     recompute_inner_meta, stack_levels)
-from .keys import (compare_padded, fnv1a_tags, lex_sort_indices_j,
-                   pack_words_j)
+from .fbtree import (BIG, EMPTY, FBTree, Level, TreeArrays, _device_build_jit,
+                     chunk_of_pos, chunk_start, recompute_inner_meta,
+                     sort_pool, stack_levels)
+from .keys import compare_padded, fnv1a_tags, pack_words_j
 from .leaf import probe
 from .traverse import TraversalEngine, resolve_engine
 
@@ -40,6 +39,7 @@ __all__ = [
     "OpReport", "BuildReport", "lookup_batch", "update_batch", "insert_batch",
     "remove_batch", "range_scan", "rebuild", "dedupe_last_wins",
     "traverse_path", "traverse_probe", "gather_live_sorted",
+    "build_from_snapshot",
 ]
 
 
@@ -885,6 +885,25 @@ class BuildReport(NamedTuple):
     error: jnp.ndarray      # bool — capacity exceeded; discard the result
 
 
+@jax.jit
+def _live_pool(tree: FBTree):
+    """The key pool with its live rows marked: ``(kb, kl, ktags, vals,
+    invalid)``, the input of ``fbtree.sort_pool``. Every live key id sits in
+    exactly one occupied leaf slot, so liveness and values scatter onto the
+    pool, and the sort runs over its ``key_cap + 1`` rows rather than over
+    every leaf slot (``ns`` times ``leaf_cap + 1``, several times more at a
+    planned capacity)."""
+    a, KC = tree.arrays, tree.config.key_cap
+    occ = a.leaf_occ.reshape(-1)                  # [(leaf_cap+1) * ns]
+    # free slots all write False/0 into the scratch row KC: the duplicate
+    # writes agree, and no live key id reaches it
+    dst = jnp.where(occ, a.leaf_keyid.reshape(-1), KC)
+    live = jnp.zeros((KC + 1,), bool).at[dst].set(occ)
+    vals = jnp.zeros((KC + 1,), a.leaf_val.dtype).at[dst].set(
+        jnp.where(occ, a.leaf_val.reshape(-1), 0))
+    return a.key_bytes, a.key_lens, a.key_tags, vals, ~live
+
+
 def gather_live_sorted(tree: FBTree):
     """Gather a tree's live key set into a sorted, compacted, pool-shaped
     snapshot: ``(kb, kl, ktags, vals, n_live)`` with rows ``[0, n_live)``
@@ -896,41 +915,32 @@ def gather_live_sorted(tree: FBTree):
     the per-shard snapshots — already globally sorted, since shards are
     range-partitioned — to re-partition on ``repro.shard.rebalance``.
     """
-    a, cfg = tree.arrays, tree.config
-    KC, L = cfg.key_cap, cfg.key_width
-    occ = a.leaf_occ.reshape(-1)                  # [(leaf_cap+1) * ns]
-    kid = jnp.where(occ, a.leaf_keyid.reshape(-1), EMPTY)
-    kid_safe = jnp.maximum(kid, 0)
-    lens = jnp.where(occ, a.key_lens[kid_safe], 0)
-    order = lex_sort_indices_j(a.key_bytes[kid_safe], lens,
-                               invalid=~occ)      # live slots first, sorted
-    n_live = occ.sum().astype(jnp.int32)
-    skid = jnp.maximum(jnp.take(kid, order), 0)
-    r = jnp.arange(order.shape[0], dtype=jnp.int32)
-    valid = r < n_live                            # n_live <= KC always
-    dst = jnp.where(valid, jnp.minimum(r, KC), KC)  # scratch row = KC
-    # invalid lanes all scatter 0/EMPTY-free zeros into the scratch row, so
-    # the duplicate writes are deterministic and the pool tail stays zero
-    kb = jnp.zeros((KC + 1, L), jnp.uint8).at[dst].set(
-        jnp.where(valid[:, None], a.key_bytes[skid], 0))
-    kl = jnp.zeros((KC + 1,), jnp.int32).at[dst].set(
-        jnp.where(valid, a.key_lens[skid], 0))
-    ktags = jnp.zeros((KC + 1,), jnp.uint8).at[dst].set(
-        jnp.where(valid, a.key_tags[skid], 0))
-    vv = jnp.zeros((KC + 1,), a.leaf_val.dtype).at[dst].set(
-        jnp.where(valid, jnp.take(a.leaf_val.reshape(-1), order), 0))
-    return kb, kl, ktags, vv, n_live
+    return sort_pool(*_live_pool(tree))
 
 
-@jax.jit
-def _rebuild_jit(tree: FBTree) -> Tuple[FBTree, BuildReport]:
+def build_from_snapshot(tree: FBTree, snapshot) -> Tuple[FBTree, BuildReport]:
+    """Rebuild ``tree`` from its :func:`gather_live_sorted` snapshot through
+    the device build core — the back half of :func:`rebuild`."""
+    kb, kl, ktags, vv, n_live = snapshot
+    arrays, err = _device_build_jit(cfg=tree.config, kb=kb, kl=kl,
+                                    ktags=ktags, vals=vv, n=n_live)
+    rep = BuildReport(n_live=n_live, n_leaves=arrays.leaf_count,
+                      reclaimed=(tree.arrays.key_count - n_live
+                                 ).astype(jnp.int32),
+                      error=err)
+    return FBTree(tree.config, arrays), rep
+
+
+def rebuild(tree: FBTree) -> Tuple[FBTree, BuildReport]:
     """Compact a split-fragmented tree by re-running the device bulk build.
 
     Gathers the live (key id, value) pairs from the leaves
-    (:func:`gather_live_sorted`: packed-word lexsort, invalid slots last,
-    pool re-packed front-to-back) and reconstructs every level — tuple and
+    (:func:`gather_live_sorted`: packed-word sort, invalid rows last, pool
+    re-packed front-to-back) and reconstructs every level — tuple and
     stacked layouts alike — through ``fbtree._device_build_from_sorted``.
-    Entirely jnp, so it composes under jit with the other batch ops.
+    Three jitted stages: marking the live pool rows, the pool sort
+    (``fbtree.sort_pool``) and the build core, which is the very program
+    ``bulk_build(device=True)`` runs, so a config compiles it once.
 
     Semantics w.r.t. the §2 protocol (DESIGN.md §5): a rebuild is a
     bulk-synchronous barrier. Tombstoned keys are dropped and the pool is
@@ -939,24 +949,15 @@ def _rebuild_jit(tree: FBTree) -> Tuple[FBTree, BuildReport]:
     cached from before the barrier (leaf ids, key ids, versions) must be
     re-resolved by a fresh traversal. The output tree is exactly what
     ``bulk_build`` (host or device) would produce from the live key set.
+
+    Telemetry: same obs contract as :func:`lookup_batch`; span
+    ``span.op.rebuild``, counters ``build.n_live``/``build.reclaimed``
+    labeled ``op=rebuild``.
     """
-    a, cfg = tree.arrays, tree.config
-    kb, kl, ktags, vv, n_live = gather_live_sorted(tree)
-    arrays, err = _device_build_from_sorted(cfg, kb, kl, ktags, vv, n_live)
-    rep = BuildReport(n_live=n_live, n_leaves=arrays.leaf_count,
-                      reclaimed=(a.key_count - n_live).astype(jnp.int32),
-                      error=err)
-    return FBTree(cfg, arrays), rep
-
-
-def rebuild(tree: FBTree) -> Tuple[FBTree, BuildReport]:
-    """Instrumented wrapper over the jitted rebuild barrier (same obs
-    contract as :func:`lookup_batch`; span ``span.op.rebuild``, counters
-    ``build.n_live``/``build.reclaimed`` labeled ``op=rebuild``)."""
     if not obs.enabled():
-        return _rebuild_jit(tree)
+        return build_from_snapshot(tree, gather_live_sorted(tree))
     with obs.span("op.rebuild"):
-        tree2, rep = _rebuild_jit(tree)
+        tree2, rep = build_from_snapshot(tree, gather_live_sorted(tree))
         host = jax.device_get(rep)
         obs.counter("op.calls", op="rebuild").inc()
         obs.counter("build.n_live", op="rebuild").inc(int(host.n_live))

@@ -42,7 +42,7 @@ from . import keys as K
 
 __all__ = ["TreeConfig", "Level", "FBTree", "bulk_build", "tree_to_device",
            "stack_levels", "chunk_start", "chunk_of_pos",
-           "recompute_inner_meta", "sharded_partition"]
+           "recompute_inner_meta", "sharded_partition", "sort_pool"]
 
 EMPTY = np.int32(-1)
 BIG = jnp.int32(2**30)
@@ -407,9 +407,10 @@ def bulk_build(cfg: TreeConfig, ks: K.KeySet, vals: np.ndarray,
     inner levels, padding to the fixed height with single-child chain nodes.
 
     ``device=True`` runs the jit-compatible device pipeline (DESIGN.md §5):
-    sort via packed-word ``jnp.lexsort``, build leaves and every inner level
-    with segmented jnp reductions (:func:`recompute_inner_meta`), the only
-    Python loop being over the O(log n) height. Both paths produce
+    sort via packed-word argsorts (:func:`_sort_raw`), build leaves and
+    every inner level with segmented jnp reductions
+    (:func:`recompute_inner_meta`), the only Python loop being over the
+    O(log n) height. Both paths produce
     bit-identical :class:`TreeArrays` (including the stacked layout) — the
     equivalence tests in ``tests/test_tree_ops.py`` pin this contract.
 
@@ -637,20 +638,46 @@ _device_build_jit = functools.partial(
     jax.jit, static_argnames=("cfg",))(_device_build_from_sorted)
 
 
+@jax.jit
+def sort_pool(kb, kl, ktags, vals, invalid):
+    """Sort and compact a pool-shaped key set (DESIGN.md §5, step 1).
+
+    Inputs are ``[key_cap + 1, ...]`` rows; rows flagged by the bool mask
+    ``invalid`` are dropped. Returns ``(kb, kl, ktags, vals, n)`` with rows
+    ``[0, n)`` holding the valid keys ascending and zeros everywhere else —
+    the input contract of :func:`_device_build_from_sorted`.
+    ``batch_ops.rebuild`` sorts the tree's key pool through it.
+    """
+    order = K.lex_sort_indices_j(kb, jnp.where(invalid, 0, kl),
+                                 invalid=invalid)   # valid rows first
+    n = (~invalid).sum().astype(jnp.int32)
+    valid = jnp.arange(kb.shape[0], dtype=jnp.int32) < n
+    keep = lambda x: jnp.where(valid.reshape((-1,) + (1,) * (x.ndim - 1)),
+                               x[order], 0).astype(x.dtype)
+    return keep(kb), keep(kl), keep(ktags), keep(vals), n
+
+
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def _sort_raw(cfg: TreeConfig, qb, ql, vals):
+    """Sort a raw batch of ``n`` keys and pad it to pool shape:
+    ``(kb, kl, ktags, vals)`` with rows ``[0, n)`` ascending and zeros past
+    them (DESIGN.md §5, step 1). The sort runs over the ``n`` keys, not
+    over the pool's ``key_cap + 1`` rows."""
+    n, R = qb.shape[0], cfg.key_cap + 1
+    ql = ql.astype(jnp.int32)
+    order = K.lex_sort_indices_j(qb, ql)
+    pad = lambda x, dt: jnp.zeros((R,) + x.shape[1:], dt).at[:n].set(
+        x.astype(dt)[order])
+    return (pad(qb, jnp.uint8), pad(ql, jnp.int32),
+            pad(K.fnv1a_tags(qb, ql), jnp.uint8), pad(vals, cfg.val_dtype))
+
+
 def _bulk_build_device(cfg: TreeConfig, ks: K.KeySet, vals) -> FBTree:
     """``bulk_build(device=True)`` body: device sort + jitted build core."""
-    n, L = ks.n, cfg.key_width
-    qb = jnp.asarray(ks.bytes)
-    ql = jnp.asarray(ks.lens).astype(jnp.int32)
-    order = K.lex_sort_indices_j(qb, ql)
-    kb = jnp.zeros((cfg.key_cap + 1, L), jnp.uint8).at[:n].set(qb[order])
-    kl = jnp.zeros((cfg.key_cap + 1,), jnp.int32).at[:n].set(ql[order])
-    ktags = jnp.zeros((cfg.key_cap + 1,), jnp.uint8).at[:n].set(
-        K.fnv1a_tags(qb, ql)[order])
-    vv = jnp.zeros((cfg.key_cap + 1,), cfg.val_dtype).at[:n].set(
-        jnp.asarray(vals).astype(cfg.val_dtype)[order])
+    kb, kl, ktags, vv = _sort_raw(cfg, jnp.asarray(ks.bytes),
+                                  jnp.asarray(ks.lens), jnp.asarray(vals))
     arrays, err = _device_build_jit(cfg=cfg, kb=kb, kl=kl, ktags=ktags,
-                                    vals=vv, n=jnp.int32(n))
+                                    vals=vv, n=jnp.int32(ks.n))
     # _check_capacity already vetted n host-side; err re-validates on device
     if bool(err):  # pragma: no cover - unreachable after _check_capacity
         raise RuntimeError("bulk_build(device=True): capacity exceeded")

@@ -111,14 +111,7 @@ def pack_words(kb: np.ndarray) -> np.ndarray:
 def pack_words_j(kb) -> "jnp.ndarray":
     """jnp version of :func:`pack_words` (order-preserving int32 words)."""
     import jax.numpy as jnp
-    L = kb.shape[-1]
-    Lp = (L + 3) // 4 * 4
-    if Lp != L:
-        pad = jnp.zeros(kb.shape[:-1] + (Lp - L,), dtype=jnp.uint8)
-        kb = jnp.concatenate([kb, pad], axis=-1)
-    w = kb.reshape(kb.shape[:-1] + (Lp // 4, 4)).astype(jnp.uint32)
-    words = (w[..., 0] << 24) | (w[..., 1] << 16) | (w[..., 2] << 8) | w[..., 3]
-    return (words ^ jnp.uint32(1 << 31)).astype(jnp.int32)
+    return jnp.stack(_word_cols_j(kb), axis=-1)
 
 
 def lex_sort_indices(ks: KeySet) -> np.ndarray:
@@ -128,19 +121,50 @@ def lex_sort_indices(ks: KeySet) -> np.ndarray:
     return np.lexsort(cols)
 
 
+def _word_cols_j(kb) -> list:
+    """The words of :func:`pack_words_j` as separate ``int32 [...]``
+    columns, assembled from byte columns. Regrouping a narrow
+    ``uint8 [N, L]`` matrix into ``[N, W, 4]`` makes the TPU compiler emit
+    code that grows with ``N`` (minutes at 2^25 rows); byte columns
+    compile in constant time."""
+    import jax.numpy as jnp
+    k = kb.astype(jnp.uint32)
+    L = kb.shape[-1]
+    cols = []
+    for w in range(-(-L // 4)):
+        word = jnp.zeros(kb.shape[:-1], jnp.uint32)
+        for b in range(min(4, L - 4 * w)):
+            word = word | (k[..., 4 * w + b] << (24 - 8 * b))
+        cols.append((word ^ jnp.uint32(1 << 31)).astype(jnp.int32))
+    return cols
+
+
 def lex_sort_indices_j(kb, kl, invalid=None) -> "jnp.ndarray":
     """jnp twin of :func:`lex_sort_indices`: device argsort of padded keys by
     (bytes asc, length tie-break), optionally pushing rows flagged by the
     bool mask ``invalid`` past every valid row. Single definition of the
     device key order — the build and rebuild paths (DESIGN.md §5) must sort
     identically for host/device parity to hold.
+
+    A least-significant-first chain of stable one-key argsorts inside a
+    ``fori_loop``, which gives exactly ``jnp.lexsort``'s order (ties keep
+    input order). One sort in a loop compiles in seconds at 2^25 rows,
+    where a many-key comparator sort takes the TPU compiler many minutes.
     """
+    import jax
     import jax.numpy as jnp
-    words = pack_words_j(kb)  # [N, W] order-preserving int32
-    cols = [kl] + [words[:, i] for i in range(words.shape[1] - 1, -1, -1)]
+    cols = [kl.astype(jnp.int32)] + _word_cols_j(kb)[::-1]
     if invalid is not None:
         cols.append(invalid.astype(jnp.int32))  # most significant: valid first
-    return jnp.lexsort(cols)
+    cols = jnp.stack(cols)              # [C, N], least significant first
+
+    def one_key(c, perm):
+        return jnp.take(perm, jnp.argsort(jnp.take(cols[c], perm),
+                                          stable=True))
+
+    n = kb.shape[0]
+    return jax.lax.fori_loop(0, cols.shape[0], one_key,
+                             jnp.arange(n, dtype=jnp.int32))
 
 
 def compare_padded(a_bytes: np.ndarray, a_len: np.ndarray,

@@ -30,14 +30,12 @@ from __future__ import annotations
 import time
 from typing import Any, Callable, List, NamedTuple, Optional, Tuple
 
-import jax.numpy as jnp
-
 from repro import obs
 
 from . import batch_ops as B
 from . import fsck
 from .faults import FaultInjected, FaultPlan
-from .fbtree import FBTree, _device_build_jit
+from .fbtree import FBTree
 
 __all__ = ["TreeVersion", "PublishReport", "TreeVersionManager"]
 
@@ -167,8 +165,8 @@ class TreeVersionManager:
     def rebuild(self, label: str = "rebuild") -> PublishReport:
         """``batch_ops.rebuild`` as an abortable publish, staged in two
         observable steps (gather, then device build) so a fault can land
-        between them. Runs the same jitted primitives as the fused
-        ``rebuild`` — the published arrays are bit-identical to it."""
+        between them. Runs the same two stages as ``rebuild`` — the
+        published arrays are bit-identical to it."""
         tree = self.current
         if not isinstance(tree, FBTree):
             raise TypeError("rebuild() needs an FBTree; use rebalance() "
@@ -176,16 +174,9 @@ class TreeVersionManager:
 
         def build():
             self._fire("lifecycle.rebuild.gather", label=label)
-            kb, kl, ktags, vv, n_live = B.gather_live_sorted(tree)
+            snapshot = B.gather_live_sorted(tree)
             self._fire("lifecycle.rebuild.build", label=label)
-            arrays, err = _device_build_jit(cfg=tree.config, kb=kb, kl=kl,
-                                            ktags=ktags, vals=vv, n=n_live)
-            rep = B.BuildReport(
-                n_live=n_live, n_leaves=arrays.leaf_count,
-                reclaimed=(tree.arrays.key_count - n_live
-                           ).astype(jnp.int32),
-                error=err)
-            return FBTree(tree.config, arrays), rep
+            return B.build_from_snapshot(tree, snapshot)
 
         return self.publish(build, label=label)
 

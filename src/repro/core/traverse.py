@@ -11,8 +11,8 @@ root-to-leaf descent. Two backend kinds live in two registries
   -> (child_ids, stats | None)``. Built-ins:
     - ``"jnp"``            pure-XLA oracle (``core.branch.branch_level``)
     - ``"pallas"``         Pallas feature-comparison kernel
-                           (``kernels.feature_branch``; interpret mode
-                           off-TPU, hardware kernel on TPU)
+                           (``kernels.feature_branch``; Mosaic-compiled
+                           on TPU, interpreted on the CPU test backend)
     - ``"binary"``         classic full-key binary search baseline
     - ``"binary+prefix"``  baseline with prefix skip
   The engine loops them over levels in either layout: ``"tuple"`` unrolls a
@@ -226,6 +226,14 @@ register_scan_backend("fused", loader=_load_fused_scan_backend)
 
 LAYOUTS = ("tuple", "stacked")
 
+# the engine refuses "fused" on a TPU rather than interpreting its kernels
+# or substituting another backend
+_FUSED_NOT_ON_TPU = (
+    "the fused descent and scan kernels do not compile for a TPU: Mosaic "
+    "refuses their per-lane row gathers, and their whole-array VMEM blocks "
+    "cannot hold a deployment-sized tree (ROADMAP A5). Use the 'jnp' or "
+    "'pallas' backend.")
+
 
 @dataclasses.dataclass(frozen=True)
 class TraversalEngine:
@@ -249,6 +257,9 @@ class TraversalEngine:
         if self.layout not in (None,) + LAYOUTS:
             raise ValueError(f"unknown layout {self.layout!r}; "
                              f"expected one of {LAYOUTS} or None")
+        if self.backend == "fused" and jax.default_backend() == "tpu":
+            raise ValueError(f"traversal backend 'fused' on a TPU: "
+                             f"{_FUSED_NOT_ON_TPU}")
 
     @property
     def kind(self) -> str:

@@ -1,3 +1,22 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels of the index's hot paths (DESIGN.md §3, §6)."""
+from __future__ import annotations
+
+import jax
+
+__all__ = ["interpret_mode"]
+
+
+def interpret_mode() -> bool:
+    """How a kernel runs on the current JAX backend: compiled through Mosaic
+    on ``tpu`` (False), through the Pallas interpreter on ``cpu`` (True,
+    the test backend). Any other backend raises: these kernels have no
+    lowering for it, and silently interpreting on an accelerator would hide
+    the device behind the interpreter."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels here compile for TPU or run interpreted on CPU; "
+        f"the {backend!r} backend has neither")

@@ -58,46 +58,52 @@ def feature_compare_rounds(feats, qfeat, knum, pcmp, *, fs: int, ns: int,
     (``kernels/fused_descent``) — the parity contract requires both to be
     bit-identical, so there is exactly one definition.
 
-    Returns ``(idx, resolved, run_lo, run_hi, rounds)``; the prefix/trivial
-    overrides are folded in (``resolved`` includes ``pcmp != 0`` and
-    ``knum <= 1``), so ``~resolved`` is exactly the billed suffix-fallback
-    lane set and ``rounds`` is already zeroed on trivial nodes. ``rounds``
-    stays all-zero (and costs nothing) when ``collect_stats`` is off.
+    Returns ``(idx, resolved, run_lo, run_hi, rounds)``, all int32
+    ``[TB, 1]``; the prefix/trivial overrides are folded in (``resolved``
+    is 1 also where ``pcmp != 0`` or ``knum <= 1``), so ``resolved == 0``
+    is exactly the billed suffix-fallback lane set and ``rounds`` is
+    already zeroed on trivial nodes. ``rounds`` stays all-zero (and costs
+    nothing) when ``collect_stats`` is off.
+
+    Every compare runs on int32 and every mask is an int32 0/1 column:
+    Mosaic refuses both the i8 -> i1 truncation a uint8 compare lowers to
+    and the relayout of a broadcast i1 mask, so no bool array is carried.
     """
     TB = feats.shape[0]
+    feats = feats.astype(jnp.int32)
+    qfeat = qfeat.astype(jnp.int32)
     lane = jax.lax.broadcasted_iota(jnp.int32, (TB, ns), 1)
-    valid = lane < knum                         # [TB, ns]
-    eq = valid
-    resolved = jnp.zeros((TB, 1), jnp.bool_)
+    eq = jnp.where(lane < knum, 1, 0)           # [TB, ns] surviving anchors
+    resolved = jnp.zeros((TB, 1), jnp.int32)
     idx = jnp.zeros((TB, 1), jnp.int32)
     rounds = jnp.zeros((TB, 1), jnp.int32)
     kmax = jnp.maximum(knum - 1, 0)
 
     for fid in range(fs):                       # unrolled: fs is 2..8
-        qb = qfeat[:, fid:fid + 1]              # [TB, 1] uint8
-        frow = feats[:, fid, :]                 # [TB, ns] uint8
-        m = (frow == qb) & eq
-        none_eq = ~m.any(axis=-1, keepdims=True)
-        less = (frow < qb) & eq
-        lo = jnp.min(jnp.where(eq, lane, ns), axis=-1, keepdims=True)
-        cnt_less = jnp.sum(less.astype(jnp.int32), axis=-1, keepdims=True)
+        qb = qfeat[:, fid:fid + 1]              # [TB, 1]
+        frow = feats[:, fid, :]                 # [TB, ns]
+        m = jnp.where(frow == qb, eq, 0)
+        none_eq = 1 - jnp.max(m, axis=-1, keepdims=True)
+        less = jnp.where(frow < qb, eq, 0)
+        lo = jnp.min(jnp.where(eq != 0, lane, ns), axis=-1, keepdims=True)
+        cnt_less = jnp.sum(less, axis=-1, keepdims=True)
         res_idx = jnp.clip(lo + cnt_less - 1, 0, kmax)
-        newly = none_eq & ~resolved
-        idx = jnp.where(newly, res_idx, idx)
+        newly = none_eq * (1 - resolved)
+        idx = jnp.where(newly != 0, res_idx, idx)
         if collect_stats:
-            rounds = rounds + (~resolved).astype(jnp.int32)
-        resolved = resolved | none_eq
-        eq = jnp.where(resolved, eq, m)
+            rounds = rounds + (1 - resolved)
+        resolved = jnp.maximum(resolved, none_eq)
+        eq = jnp.where(resolved != 0, eq, m)
 
-    run_lo = jnp.min(jnp.where(eq, lane, ns), axis=-1, keepdims=True)
-    run_hi = jnp.max(jnp.where(eq, lane, -1), axis=-1, keepdims=True)
+    run_lo = jnp.min(jnp.where(eq != 0, lane, ns), axis=-1, keepdims=True)
+    run_hi = jnp.max(jnp.where(eq != 0, lane, -1), axis=-1, keepdims=True)
 
     idx = jnp.where(pcmp < 0, 0, idx)
     idx = jnp.where(pcmp > 0, kmax, idx)
-    resolved = resolved | (pcmp != 0)
+    resolved = jnp.where(pcmp != 0, 1, resolved)
     trivial = knum <= 1
     idx = jnp.where(trivial, 0, idx)
-    resolved = resolved | trivial
+    resolved = jnp.where(trivial, 1, resolved)
     if collect_stats:
         rounds = jnp.where(trivial, 0, rounds)
     return idx, resolved, run_lo, run_hi, rounds
@@ -109,7 +115,7 @@ def _kernel(feats_ref, qfeat_ref, knum_ref, pcmp_ref, *out_refs, fs: int,
         feats_ref[...], qfeat_ref[...], knum_ref[...], pcmp_ref[...],
         fs=fs, ns=ns, collect_stats=collect_stats)
     out_refs[0][...] = idx
-    out_refs[1][...] = resolved.astype(jnp.int32)
+    out_refs[1][...] = resolved
     out_refs[2][...] = run_lo
     out_refs[3][...] = run_hi
     if collect_stats:
@@ -119,7 +125,7 @@ def _kernel(feats_ref, qfeat_ref, knum_ref, pcmp_ref, *out_refs, fs: int,
 @functools.partial(jax.jit,
                    static_argnames=("tile_b", "interpret", "collect_stats"))
 def feature_branch_kernel(feats, qfeat, knum, pcmp, tile_b: int = DEFAULT_TILE_B,
-                          interpret: bool = True, collect_stats: bool = True):
+                          *, interpret: bool, collect_stats: bool = True):
     """B must be a multiple of tile_b (ops.py pads). With
     ``collect_stats=False`` the rounds output (and its in-kernel
     accumulator) is dropped — 4 outputs instead of 5."""

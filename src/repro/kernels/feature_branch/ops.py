@@ -4,7 +4,8 @@ Registered as the ``"pallas"`` backend in the traversal-engine registry
 (``core.traverse``) — drop-in for core.branch.branch_level with identical
 BranchStats accounting (and the same static ``collect_stats`` switch). The
 gather / prefix-compare stages run in XLA, the feature-comparison hot loop
-in Pallas (interpret mode off-TPU), and the suffix fallback shares
+in Pallas (compiled by Mosaic on a TPU, interpreted on the CPU test
+backend: ``kernels.interpret_mode``), and the suffix fallback shares
 ``core.branch.suffix_binary_search``: a while-loop bounded by the widest
 surviving equal run, so levels where no lane needs the fallback (e.g.
 single-child chain levels, knum <= 1 everywhere) skip the anchor-gather
@@ -12,18 +13,14 @@ compare rounds entirely instead of burning ``ns.bit_length()`` dead rounds.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.core.branch import (BranchStats, _first_diff_cmp,
                                suffix_binary_search)
 
+from .. import interpret_mode
 from .kernel import DEFAULT_TILE_B, auto_tile, feature_branch_kernel
 from .ref import feature_branch_ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def feature_branch(feats, qfeat, knum, pcmp, use_pallas: bool = True,
@@ -47,7 +44,7 @@ def feature_branch(feats, qfeat, knum, pcmp, use_pallas: bool = True,
         knum = jnp.pad(knum, [(0, Bp - B), (0, 0)])
         pcmp = jnp.pad(pcmp, [(0, Bp - B), (0, 0)])
     outs = feature_branch_kernel(feats, qfeat, knum, pcmp, tile_b=tile_b,
-                                 interpret=not _on_tpu(),
+                                 interpret=interpret_mode(),
                                  collect_stats=collect_stats)
     return tuple(o[:B] for o in outs)
 

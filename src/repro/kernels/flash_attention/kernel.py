@@ -86,7 +86,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention_kernel(q, k, v, *, scale: float, kv_len: int,
                            causal: bool = True, window: int = 0,
                            prefix_len: int = 0, block_q: int = 512,
-                           block_k: int = 512, interpret: bool = True):
+                           block_k: int = 512, interpret: bool):
     """q [BH, S, hd], k/v [BH, T, hv] (heads pre-flattened, kv pre-repeated,
     S and T padded to block multiples). Returns [BH, S, hv]."""
     BH, S, hd = q.shape

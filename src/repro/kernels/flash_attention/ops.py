@@ -2,7 +2,8 @@
 
 ``flash_sdpa`` is a drop-in for models.attention.sdpa: it flattens
 (batch, kv-head, rep) onto one grid axis, repeats kv per group, pads S/T to
-block multiples, and calls the Pallas kernel (interpret mode off-TPU).
+block multiples, and calls the Pallas kernel (compiled on a TPU,
+interpreted on the CPU test backend: ``kernels.interpret_mode``).
 Backward recomputes attention with the jnp flash path (standard
 flash-attention recompute strategy — no tile residuals are stored).
 """
@@ -15,11 +16,8 @@ import jax.numpy as jnp
 
 from repro.models.attention import MaskSpec, _sdpa_flash
 
+from .. import interpret_mode
 from .kernel import flash_attention_kernel
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
@@ -50,7 +48,7 @@ def _flash_fwd_impl(q, k, v, mask, n_rep, scale, block: int = 512):
         qf, kf, vf, scale=scale, kv_len=T,
         causal=(mask.kind == "causal"), window=mask.window,
         prefix_len=mask.prefix_len, block_q=bq, block_k=bk,
-        interpret=not _on_tpu())
+        interpret=interpret_mode())
     out = out.reshape(B, Hk, n_rep, Sp, hv).transpose(0, 3, 1, 2, 4)
     return out[:, :S].reshape(B, S, Hk * n_rep * hv)
 

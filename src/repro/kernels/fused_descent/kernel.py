@@ -30,9 +30,13 @@ anchor gathers, the [TB, ns, L] probe verify), so the tile cap halves from
 256 at the paper's ns=64 to 128 at the TPU-natural ns=128
 (:func:`descent_tile`).
 
-Off-TPU this runs in interpret mode like every kernel in the repo; the tree
-arrays ride in whole-array blocks, which interpret mode tolerates at any
-size (a real-TPU deployment would stream level blocks per grid step).
+This kernel runs only in the Pallas interpreter (the CPU test backend). It
+does not compile for a TPU: Mosaic refuses its in-kernel row gathers by
+per-lane node id ("Only 2D gather is supported"), and the tree state rides
+as whole-array VMEM blocks, which a deployment-sized tree (about 3 GB at
+2^25 keys) can never fit. ``core.traverse`` therefore refuses the
+``"fused"`` backend on a TPU; a chip version keeps the tree in HBM and
+fetches rows per lane by DMA (ROADMAP A5).
 """
 from __future__ import annotations
 
@@ -131,7 +135,7 @@ def descend_levels(qb, ql, knum_a, plen_a, prefix_a, feats_a, child_a,
             collect_stats=collect_stats)
         kmax = jnp.maximum(kn - 1, 0)
         trivial = kn <= 1
-        need_bs = ~resolved                   # = billed: excl. pcmp/trivial
+        need_bs = resolved == 0               # = billed: excl. pcmp/trivial
 
         # suffix binary search over the surviving run, width-bounded
         lo_b = jnp.where(need_bs, run_lo, 0)
@@ -299,15 +303,15 @@ def _kernel(*refs, n_levels: int, fs: int, ns: int, L: int,
 def fused_descent_kernel(qb, ql, qtag, stacked_arrays, key_bytes, key_lens,
                          leaf_arrays, tile_b: int, n_levels: int, fs: int,
                          ns: int, sibling_check: bool, with_probe: bool,
-                         collect_stats: bool, interpret: bool = True):
+                         collect_stats: bool, interpret: bool):
     """One pallas_call for descent (+ sibling hop + leaf probe).
 
     ``stacked_arrays = (knum, plen, prefix, features, children, anchors)``
     stacked over levels; ``leaf_arrays = (high, next)`` + ``(tags, occ_u8,
     keyid, val)`` when probing (pass ``()`` slices when a stage is off).
     B must be a multiple of tile_b (ops.py pads). Queries are tiled over the
-    grid; tree state rides as whole-array blocks (interpret-mode friendly;
-    a real-TPU build would stream per-level blocks).
+    grid; tree state rides as whole-array VMEM blocks, so this compiles for
+    the interpreter only (see the module docstring).
     """
     B, L = qb.shape
     assert B % tile_b == 0, (B, tile_b)

@@ -12,7 +12,6 @@ from __future__ import annotations
 
 from typing import List, Optional, Tuple
 
-import jax
 import jax.numpy as jnp
 
 from repro.core.branch import BranchStats
@@ -20,11 +19,8 @@ from repro.core.fbtree import FBTree
 from repro.core.keys import fnv1a_tags
 from repro.core.leaf import LeafStats
 
+from .. import interpret_mode
 from .kernel import descent_tile, fused_descent_kernel
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _run(tree: FBTree, qb, ql, sibling_check: bool, with_probe: bool,
@@ -61,7 +57,7 @@ def _run(tree: FBTree, qb, ql, sibling_check: bool, with_probe: bool,
         qb_p, ql_p[:, None], qtag_p, stacked_arrays, a.key_bytes,
         a.key_lens[:, None], leaf_arrays, tile_b=tile_b, n_levels=n_levels,
         fs=fs, ns=ns, sibling_check=sibling_check, with_probe=with_probe,
-        collect_stats=collect_stats, interpret=not _on_tpu())
+        collect_stats=collect_stats, interpret=interpret_mode())
     outs = [o[:B] for o in outs]
 
     it = iter(outs)

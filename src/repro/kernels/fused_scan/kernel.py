@@ -31,9 +31,12 @@ destination positions are unique per row, so the reduction is exact. Static
 ``collect_stats`` drops the ``rearranged`` accumulator and output from the
 compiled kernel; emitted pairs are bit-identical either way.
 
-Off-TPU this runs in interpret mode like every kernel in the repo; tree
-state rides in whole-array blocks (a real-TPU deployment would stream the
-chain through double-buffered leaf blocks).
+Like ``fused_descent``, whose level loop it reuses, this kernel runs only
+in the Pallas interpreter (the CPU test backend): Mosaic refuses the
+per-lane row gathers, and whole-array VMEM blocks cannot hold a
+deployment-sized tree. ``core.traverse`` refuses the ``"fused"`` backend on
+a TPU; a chip version would stream the chain through double-buffered leaf
+blocks fetched from HBM (ROADMAP A5).
 """
 from __future__ import annotations
 
@@ -237,14 +240,14 @@ def _kernel(*refs, n_levels: int, fs: int, ns: int, L: int, max_items: int,
 def fused_scan_kernel(qb, ql, stacked_arrays, key_bytes, key_lens,
                       leaf_arrays, tile_b: int, n_levels: int, fs: int,
                       ns: int, max_items: int, collect_stats: bool,
-                      interpret: bool = True):
+                      interpret: bool):
     """One pallas_call for descent + sibling hop + leaf-chain range scan.
 
     ``stacked_arrays = (knum, plen, prefix, features, children, anchors)``
     stacked over levels; ``leaf_arrays = (high, next, keyid, val, occ_u8,
     ordered_u8)``. B must be a multiple of tile_b (ops.py pads). Queries
-    tile over the grid; tree state rides as whole-array blocks
-    (interpret-mode friendly; a real-TPU build would stream leaf blocks).
+    tile over the grid; tree state rides as whole-array VMEM blocks, so
+    this compiles for the interpreter only (see the module docstring).
     """
     B, L = qb.shape
     assert B % tile_b == 0, (B, tile_b)

@@ -13,16 +13,12 @@ from __future__ import annotations
 
 from typing import Tuple
 
-import jax
 import jax.numpy as jnp
 
 from repro.core.fbtree import FBTree
 
+from .. import interpret_mode
 from .kernel import descent_tile, fused_scan_kernel
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def fused_range_scan(tree: FBTree, qb, ql, max_items: int = 64,
@@ -64,7 +60,7 @@ def fused_range_scan(tree: FBTree, qb, ql, max_items: int = 64,
         qb_p, ql_p[:, None], stacked_arrays, a.key_bytes,
         a.key_lens[:, None], leaf_arrays, tile_b=tile_b, n_levels=n_levels,
         fs=fs, ns=ns, max_items=max_items, collect_stats=collect_stats,
-        interpret=not _on_tpu())
+        interpret=interpret_mode())
     outs = [o[:B] for o in outs]
     out_kid, out_val = outs[0], outs[1]
     emitted = outs[2][:, 0]

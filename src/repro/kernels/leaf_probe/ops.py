@@ -1,18 +1,14 @@
 """Jitted wrapper: full leaf probe (tag filter kernel + exact verify in XLA)."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.core.keys import fnv1a_tags
 from repro.core.leaf import LeafStats, verify_candidates
 
+from .. import interpret_mode
 from .kernel import DEFAULT_TILE_B, leaf_probe_kernel
 from ..feature_branch.kernel import auto_tile
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def leaf_probe(tags, occ, qtag, use_pallas: bool = True, tile_b: int = None):
@@ -30,7 +26,7 @@ def leaf_probe(tags, occ, qtag, use_pallas: bool = True, tile_b: int = None):
         occ = jnp.pad(occ, [(0, Bp - B), (0, 0)])
         qtag = jnp.pad(qtag, [(0, Bp - B), (0, 0)], constant_values=1)
     outs = leaf_probe_kernel(tags, occ.astype(jnp.uint8), qtag,
-                             tile_b=tile_b, interpret=not _on_tpu())
+                             tile_b=tile_b, interpret=interpret_mode())
     return tuple(o[:B] for o in outs)
 
 

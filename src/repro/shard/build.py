@@ -8,12 +8,16 @@ contract holds shard by shard), and the runs' minimum keys become the
 replicated router. Every shard shares one ``TreeConfig`` planned for
 ``per_shard_max_keys`` (default: the full ``max_keys``, so any single
 shard can absorb the whole key set before a ``rebalance`` — skew-safe, at
-S× pool memory; pass a tighter value when memory matters).
+S× pool memory; pass each shard's share, ``max_keys / n_shards`` plus
+headroom, when memory matters, as a deployment filling its chips does).
+Each shard is built on its own device, so no device carries another
+shard's build temporaries.
 """
 from __future__ import annotations
 
 from typing import Any, Optional
 
+import jax
 import numpy as np
 
 from repro.core import keys as K
@@ -89,7 +93,11 @@ def sharded_build(ks: K.KeySet, vals, n_shards: int,
     devices = shard_devices(mesh, n_shards)
     shards = []
     for (pks, pvals), dev in zip(parts, devices):
-        t = bulk_build(cfg, pks, np.asarray(pvals), device=device)
+        # build where the shard lives: the device build's inputs and
+        # temporaries land on `dev` (None: the default device), and placing
+        # it is then a no-op
+        with jax.default_device(dev):
+            t = bulk_build(cfg, pks, np.asarray(pvals), device=device)
         shards.append(place_shard(t, dev))
     return ShardedTree(shards=tuple(shards), router=make_router(split_keys),
                        devices=devices, mesh=mesh)

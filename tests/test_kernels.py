@@ -166,3 +166,28 @@ def test_flash_sdpa_gqa_and_grads(rng):
                               ** 2).sum())(q)
     g2 = jax.grad(lambda q_: (_sdpa_small(q_, k, v, spec, 4) ** 2).sum())(q)
     assert float(jnp.abs(g1 - g2).max()) < 1e-3
+
+
+# ------------------------------------------------------- backend selection
+@pytest.mark.parametrize("backend,interprets", [("cpu", True), ("tpu", False),
+                                                ("gpu", None)])
+def test_interpret_mode_follows_the_backend(monkeypatch, backend,
+                                            interprets):
+    """Kernels interpret only on the CPU and compile on a TPU; any other
+    backend raises instead of hiding the device behind the interpreter.
+    ``fused`` is refused on a TPU at engine construction."""
+    import jax
+    from repro.core.traverse import TraversalEngine
+    from repro.kernels import interpret_mode
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if interprets is None:
+        with pytest.raises(RuntimeError, match="gpu"):
+            interpret_mode()
+    else:
+        assert interpret_mode() is interprets
+    TraversalEngine("pallas")
+    if backend == "tpu":
+        with pytest.raises(ValueError, match="do not compile for a TPU"):
+            TraversalEngine("fused")
+    else:
+        TraversalEngine("fused")

@@ -432,6 +432,7 @@ def test_forced_multi_device_mesh():
     device, ops parity across devices."""
     import os
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"     # the parent may hold the accelerator
     env.pop("XLA_FLAGS", None)
     env["PYTHONPATH"] = "src" + (
         os.pathsep + env["PYTHONPATH"] if "PYTHONPATH" in env else "")

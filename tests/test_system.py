@@ -63,6 +63,7 @@ def test_dryrun_single_cell_smoke():
     )
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    env["JAX_PLATFORMS"] = "cpu"     # the parent may hold the accelerator
     env.pop("XLA_FLAGS", None)
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, timeout=560, env=env, cwd=".")
