@@ -98,6 +98,10 @@ def _traverse_probe(tree: FBTree, qb, ql, engine, sibling_check=True):
     e.g. ``"fused"``) collapse the whole pipeline into one kernel launch;
     level backends run the engine descent followed by the probe. Stats may
     be ``None`` under a stats-free engine — ``_report`` zero-fills.
+
+    The probe's ops carry the named scope ``leaf_probe`` (the descent's
+    carry ``descent``, see ``TraversalEngine.traverse``): metadata only,
+    so a device trace can split the program's time between the two.
     """
     eng = resolve_engine(engine)
     fused = eng.probe_path()
@@ -106,8 +110,9 @@ def _traverse_probe(tree: FBTree, qb, ql, engine, sibling_check=True):
                      collect_stats=eng.collect_stats)
     leaf_ids, path, bstats = eng.traverse(
         tree, qb, ql, sibling_check=sibling_check)
-    found, slot, val, lstats = probe(tree, leaf_ids, qb, ql,
-                                     collect_stats=eng.collect_stats)
+    with jax.named_scope("leaf_probe"):
+        found, slot, val, lstats = probe(tree, leaf_ids, qb, ql,
+                                         collect_stats=eng.collect_stats)
     return leaf_ids, path, found, slot, val, bstats, lstats
 
 
@@ -187,17 +192,16 @@ def lookup_batch(tree: FBTree, qb, ql, sibling_check: bool = True,
                  engine: Optional[TraversalEngine] = None):
     """Batched point lookup. Returns (vals [B], report).
 
-    Telemetry (DESIGN.md §9): with ``repro.obs`` enabled, the launch runs
-    under a host span (latency histogram ``span.op.lookup``) and the
-    report's device counters drain into the registry — one host sync per
-    batch. With it off (the default) this is the bare jitted call; the
-    traced program is identical either way.
+    Telemetry (DESIGN.md §9): the launch always runs under the span
+    ``op.lookup`` — a profiler annotation, plus the host-time histogram
+    ``span.op.lookup`` with ``repro.obs`` enabled. Only with it enabled,
+    and after the span, the report's device counters drain into the
+    registry: one host sync per batch. With it off nothing waits for the
+    device; the traced program is identical either way.
     """
-    if not obs.enabled():
-        return _lookup_batch_jit(tree, qb, ql, sibling_check, engine)
     with obs.span("op.lookup"):
         val, rep = _lookup_batch_jit(tree, qb, ql, sibling_check, engine)
-        obs.drain_op_report("lookup", rep)
+    obs.drain_op_report("lookup", rep)
     return val, rep
 
 
@@ -232,11 +236,9 @@ def update_batch(tree: FBTree, qb, ql, vals,
                  engine: Optional[TraversalEngine] = None, mask=None):
     """Instrumented wrapper over the jitted blind update (see the jit
     body's docstring; same obs contract as :func:`lookup_batch`)."""
-    if not obs.enabled():
-        return _update_batch_jit(tree, qb, ql, vals, engine, mask)
     with obs.span("op.update"):
         tree2, rep = _update_batch_jit(tree, qb, ql, vals, engine, mask)
-        obs.drain_op_report("update", rep)
+    obs.drain_op_report("update", rep)
     return tree2, rep
 
 
@@ -267,11 +269,9 @@ def remove_batch(tree: FBTree, qb, ql,
                  engine: Optional[TraversalEngine] = None, mask=None):
     """Instrumented wrapper over the jitted tombstone removal (same obs
     contract as :func:`lookup_batch`)."""
-    if not obs.enabled():
-        return _remove_batch_jit(tree, qb, ql, engine, mask)
     with obs.span("op.remove"):
         tree2, rep = _remove_batch_jit(tree, qb, ql, engine, mask)
-        obs.drain_op_report("remove", rep)
+    obs.drain_op_report("remove", rep)
     return tree2, rep
 
 
@@ -641,16 +641,16 @@ def insert_batch(tree: FBTree, qb, ql, vals, max_ov: int = 128,
     no-ops — no in-place update, no pool append, never pending.
 
     Telemetry: same obs contract as :func:`lookup_batch`, plus an
-    ``op.rounds`` counter (split rounds taken, labeled ``op=insert``).
+    ``op.rounds`` counter (split rounds taken, labeled ``op=insert``). The
+    span covers the split rounds and their own host syncs (each round
+    reads ``pending`` and the error flag), which the insert needs with
+    telemetry on or off.
     """
-    if not obs.enabled():
-        return _insert_batch_impl(tree, qb, ql, vals, max_ov, ins_cap,
-                                  max_rounds, engine, mask)
     with obs.span("op.insert"):
         tree2, rep, rounds = _insert_batch_impl(
             tree, qb, ql, vals, max_ov, ins_cap, max_rounds, engine, mask)
-        obs.drain_op_report("insert", rep)
-        obs.counter("op.rounds", op="insert").inc(rounds)
+    obs.drain_op_report("insert", rep)
+    obs.counter("op.rounds", op="insert").inc(rounds)
     return tree2, rep, rounds
 
 
@@ -852,19 +852,19 @@ def range_scan(tree: FBTree, qb, ql, max_items: int = 64,
     rearranged [B])``; ``rearranged`` (dirty leaves visited) is all-zero
     under a stats-free engine.
 
-    Telemetry: same obs contract as :func:`lookup_batch` — the span
-    histogram is ``span.op.scan``, and ``op.emitted``/``op.rearranged``
-    counters drain from the scan outputs (one host sync).
+    Telemetry: same obs contract as :func:`lookup_batch` — the span is
+    ``op.scan``, and with telemetry enabled ``op.emitted``/
+    ``op.rearranged`` counters drain from the scan outputs (one host
+    sync, after the span).
     """
     if max_items < 1:
         raise ValueError(
             f"range_scan: max_items must be >= 1, got {max_items} — each "
             f"lane emits up to max_items (key, value) pairs")
-    if not obs.enabled():
-        return _range_scan_jit(tree, qb, ql, max_items, engine)
     with obs.span("op.scan"):
         out_kid, out_val, emitted, rearranged = _range_scan_jit(
             tree, qb, ql, max_items, engine)
+    if obs.enabled():
         em, re = jax.device_get((emitted, rearranged))
         obs.counter("op.calls", op="scan").inc()
         obs.counter("op.lanes", op="scan").inc(int(em.size))
@@ -951,13 +951,12 @@ def rebuild(tree: FBTree) -> Tuple[FBTree, BuildReport]:
     ``bulk_build`` (host or device) would produce from the live key set.
 
     Telemetry: same obs contract as :func:`lookup_batch`; span
-    ``span.op.rebuild``, counters ``build.n_live``/``build.reclaimed``
-    labeled ``op=rebuild``.
+    ``op.rebuild``, and with telemetry enabled counters
+    ``build.n_live``/``build.reclaimed`` labeled ``op=rebuild``.
     """
-    if not obs.enabled():
-        return build_from_snapshot(tree, gather_live_sorted(tree))
     with obs.span("op.rebuild"):
         tree2, rep = build_from_snapshot(tree, gather_live_sorted(tree))
+    if obs.enabled():
         host = jax.device_get(rep)
         obs.counter("op.calls", op="rebuild").inc()
         obs.counter("build.n_live", op="rebuild").inc(int(host.n_live))

@@ -289,7 +289,17 @@ class TraversalEngine:
                  ) -> Tuple[jnp.ndarray, List[jnp.ndarray], BranchStats]:
         """Descend all inner levels. Returns (leaf_ids, path, stats) where
         ``path[l]`` is each query's node id AT level ``l`` (root first) —
-        the parent chain the split path propagates anchors through."""
+        the parent chain the split path propagates anchors through.
+
+        The descent's ops carry the named scope ``descent``, and under the
+        ``tuple`` layout each inner level's ops ``descent/level_<l>``
+        (a ``stacked`` scan has one body for every level). Named scopes
+        are metadata: the same ops and results, labelled for a trace."""
+        with jax.named_scope("descent"):
+            return self._traverse(tree, qb, ql, sibling_check)
+
+    def _traverse(self, tree: FBTree, qb: jnp.ndarray, ql: jnp.ndarray,
+                  sibling_check: bool):
         B = qb.shape[0]
         cs = self.collect_stats
 
@@ -306,10 +316,11 @@ class TraversalEngine:
 
         if self.resolve_layout(tree) == "tuple":
             path = []
-            for level in a.levels:
+            for l, level in enumerate(a.levels):
                 path.append(node_ids)
-                node_ids, s = fn(level, a.key_bytes, a.key_lens, node_ids,
-                                 qb, ql, collect_stats=cs)
+                with jax.named_scope(f"level_{l}"):
+                    node_ids, s = fn(level, a.key_bytes, a.key_lens,
+                                     node_ids, qb, ql, collect_stats=cs)
                 if cs:
                     stats = stats + s
         elif cs:

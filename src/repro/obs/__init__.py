@@ -1,21 +1,22 @@
 """Telemetry subsystem: spans, metrics, device-counter export, events
 (DESIGN.md §9).
 
-Zero-cost when off — the default. Every instrumented call site in the
-engine, shard dispatch, lifecycle, fault, and serving layers goes through
-this surface, and with collection disabled each one reduces to a single
-predicate check on the host; nothing obs-related ever enters a jitted
-program, so compiled HLO and op outputs are bit-identical either way
-(pinned by ``tests/test_obs.py``). Enable with :func:`enable` or
-``REPRO_OBS=1``.
+Every instrumented call site in the engine, shard dispatch, lifecycle,
+fault, and serving layers goes through this surface. Spans always emit a
+``jax.profiler.TraceAnnotation`` (free unless a profiler is capturing);
+histograms, counters, events and the device-counter drains (a host sync
+per op) run only while collection is enabled, which is off by default.
+Nothing obs-related ever enters a jitted program, so compiled HLO and op
+outputs are bit-identical either way (pinned by ``tests/test_obs.py``).
+Enable with :func:`enable` or ``REPRO_OBS=1``.
 
 Quick tour::
 
     from repro import obs
 
     obs.enable()
-    with obs.span("descent", shard=0):          # host span + profiler
-        vals, rep = lookup_batch(tree, qb, ql)  #   TraceAnnotation
+    with obs.span("wave", n=4):                 # TraceAnnotation "wave"
+        vals, rep = lookup_batch(tree, qb, ql)  #   + histogram span.wave
     obs.histogram("serve.request_latency_s").observe(dt)
     obs.counter("shard.retries", op="lookup").inc()
     obs.event("publish", label="compact", version=1, ok=True,
@@ -25,7 +26,7 @@ Quick tour::
 
 Stable public surface — import from here, not from the submodules.
 """
-from .bridge import drain_op_report, drain_stats
+from .bridge import drain_op_report
 from .events import (EVENT_TYPES, event, event_summary, events,
                      validate_event)
 from .export import console_summary, export_events_jsonl, prometheus_text
@@ -43,7 +44,7 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "HIST_BOUNDS",
     "counter", "gauge", "histogram", "get_metric", "all_metrics",
     # device-counter bridge
-    "drain_stats", "drain_op_report",
+    "drain_op_report",
     # events
     "EVENT_TYPES", "event", "events", "event_summary", "validate_event",
     # exporters

@@ -1,4 +1,4 @@
-"""Device-counter export: drain stats pytrees into the registry.
+"""Device-counter export: drain op reports into the registry.
 
 The tree's modeled hardware counters (``BranchStats``/``LeafStats``, and
 the op-level ``OpReport``/``BuildReport`` aggregates built from them) are
@@ -8,6 +8,11 @@ drain. This bridge is the host-side sink for the stats-on path: ONE
 ``jax.device_get`` per batch pulls the entire pytree across (never a
 per-level or per-field sync), then per-lane counters are summed into
 registry counters named ``tree.<field>`` labeled by op.
+
+A drain is a host sync: it waits for the op's program to finish. It runs
+only with telemetry enabled, after the op's span has closed, so enabling
+telemetry is an operator's choice to trade that wait (which serialises
+back-to-back launches) for the counters; with it off no op syncs.
 
 Draining preserves the compile-away contract by construction: it only
 touches values the op already returned, so enabling telemetry changes no
@@ -21,7 +26,7 @@ from typing import Optional
 
 from . import registry as _reg
 
-__all__ = ["drain_stats", "drain_op_report"]
+__all__ = ["drain_op_report"]
 
 # OpReport counter columns that come from BranchStats/LeafStats
 # (DESIGN.md §3); `found` et al. are outcomes, not device counters.
@@ -32,17 +37,6 @@ _REPORT_COUNTERS = ("feat_rounds", "suffix_bs", "key_compares",
 def _host(pytree):
     import jax
     return jax.device_get(pytree)
-
-
-def drain_stats(stats, prefix: str = "tree", **labels) -> None:
-    """Drain one stats NamedTuple (``BranchStats``/``LeafStats``) into
-    counters ``<prefix>.<field>``. ``stats=None`` (stats-free engine) is a
-    no-op, as is a disabled registry."""
-    if not _reg.enabled() or stats is None:
-        return
-    host = _host(stats)                        # one device->host sync
-    for f, col in zip(stats._fields, host):
-        _reg.counter(f"{prefix}.{f}", **labels).inc(int(col.sum()))
 
 
 def drain_op_report(op: str, rep, batch: Optional[int] = None) -> None:

@@ -4,8 +4,9 @@ The registry is the host-side half of the telemetry subsystem
 (DESIGN.md §9). Everything hangs off one module-global ``enabled`` flag:
 
 * **off** (the default) — ``counter()``/``gauge()``/``histogram()`` return
-  a shared no-op metric and :func:`span`/:func:`event` short-circuit, so
-  instrumented call sites cost one predicate check. Nothing obs-related is
+  a shared no-op metric, :func:`event` short-circuits and :func:`span` is
+  a bare profiler annotation, so instrumented call sites cost one
+  predicate check (and, for spans, one annotation). Nothing obs-related is
   ever traced into a jitted program either way — instrumentation lives at
   the host call sites around jitted launches, which is what keeps the
   zero-cost contract bit-exact (same HLO, same outputs) rather than merely

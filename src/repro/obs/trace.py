@@ -1,21 +1,26 @@
 """Nestable host-side tracing spans (DESIGN.md §9).
 
-``span("descent", shard=0)`` times a host-side region into the metrics
-registry (histogram ``span.<dotted.path>``, the path being the names of
-the enclosing spans joined with dots, so the same leaf name nested under
-different parents stays distinguishable) and, when the JAX profiler is
-capturing, emits a ``jax.profiler.TraceAnnotation`` so the host region
-lines up with the device timeline in the trace viewer.
+``span("op.lookup")`` always enters a ``jax.profiler.TraceAnnotation``
+named exactly ``name`` (no labels, no parent path: one name is one kind
+of span in a trace), so a profiler capturing the process sees the host
+region beside the device timeline. With no profiler capturing, the
+annotation costs well under a microsecond.
 
-While telemetry is off, ``span`` hands back a shared null context manager
-— one predicate check per call site, nothing recorded, and never anything
-inside a jitted program (spans wrap launches; they are invisible to
-tracing, which is what keeps compiled HLO byte-identical either way).
+With telemetry enabled, the span also times the region into the metrics
+registry: histogram ``span.<dotted.path>``, the path being the names of
+the enclosing spans joined with dots (the same leaf name nested under
+different parents stays distinguishable), labelled with ``labels``.
+
+Never anything inside a jitted program: spans wrap launches and are
+invisible to tracing, which keeps compiled HLO byte-identical with
+telemetry on or off.
 """
 from __future__ import annotations
 
 import time
-from typing import List, Optional
+from typing import List
+
+from jax import profiler as _profiler
 
 from . import registry as _reg
 
@@ -25,21 +30,9 @@ _STACK: List[str] = []
 
 
 def current_path() -> str:
-    """Dotted path of the innermost open span ("" at top level)."""
+    """Dotted path of the innermost open registry span ("" at top level;
+    spans entered while telemetry is off are not on the path)."""
     return ".".join(_STACK)
-
-
-class _NullSpan:
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL = _NullSpan()
 
 
 class _Span:
@@ -50,27 +43,18 @@ class _Span:
         self.labels = labels
         self.path = ""
         self.t0 = 0.0
-        self._annot = None
+        self._annot = _profiler.TraceAnnotation(name)
 
     def __enter__(self):
         _STACK.append(self.name)
         self.path = ".".join(_STACK)
-        try:                       # device-timeline alignment is best-effort
-            import jax.profiler
-            self._annot = jax.profiler.TraceAnnotation(self.path)
-            self._annot.__enter__()
-        except Exception:
-            self._annot = None
+        self._annot.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         dt = time.perf_counter() - self.t0
-        if self._annot is not None:
-            try:
-                self._annot.__exit__(*exc)
-            except Exception:
-                pass
+        self._annot.__exit__(*exc)
         if _STACK and _STACK[-1] == self.name:
             _STACK.pop()
         _reg.histogram(f"span.{self.path}", **self.labels).observe(dt)
@@ -78,9 +62,10 @@ class _Span:
 
 
 def span(name: str, **labels):
-    """Context manager timing a host region into histogram
-    ``span.<path>`` (labels become metric labels — keep their cardinality
-    bounded: shard ids and op names, not batch contents)."""
+    """Context manager over a host region: a profiler annotation named
+    ``name`` always, and with telemetry enabled a duration sample in
+    histogram ``span.<path>`` (labels become metric labels — keep their
+    cardinality bounded: shard ids and op names, not batch contents)."""
     if not _reg.enabled():
-        return _NULL
+        return _profiler.TraceAnnotation(name)
     return _Span(name, labels)

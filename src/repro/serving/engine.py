@@ -132,7 +132,7 @@ class Engine:
             # prefill the whole prompt for the engine cache (single call),
             # but only *new* blocks are published to the page store
             toks = jnp.asarray(r.prompt, jnp.int32)[None]
-            with obs.span("serve.prefill", rid=r.rid):
+            with obs.span("serve.prefill"):
                 logits, c = self._prefill(self.params, toks)
             k = np.array(self.cache.k)
             v = np.array(self.cache.v)

@@ -31,6 +31,13 @@ last-barrier ``snapshots`` replica (``degraded`` mask — possibly stale),
 mutations and scans report those lanes ``failed`` (never silently
 dropped or truncated), and :func:`rebalance` is the recovery barrier that
 re-admits the shard with fresh health and snapshots.
+
+Telemetry (DESIGN.md §9): the layer's three kinds of host work each run
+under a span, none inside another — ``shard.route`` (the router's launch
+and the owner fetch; in a scan, also each shard's parked lanes),
+``shard.dispatch`` (one shard's launch, retries included; the shard op's
+own ``op.<name>`` span nests in it) and ``shard.combine`` (per-shard
+results pulled to the host and merged by owner).
 """
 from __future__ import annotations
 
@@ -97,18 +104,21 @@ def _put(x, dev):
 
 
 def _owner_masks(st: ShardedTree, qb, ql):
-    """Route once; per-shard owner masks as host bools."""
-    qb = jnp.asarray(qb)
-    if qb.ndim != 2 or qb.shape[-1] != st.config.key_width:
-        got = "x".join(map(str, qb.shape))
-        raise ValueError(
-            f"query batch shape [{got}] does not match the tree's key "
-            f"width {st.config.key_width}: routing compares packed words, "
-            f"so keys must be zero-padded to exactly key_width bytes — "
-            f"build them with repro.core.keys.make_keyset(keys, "
-            f"max_key_len={st.config.key_width})")
-    ql = jnp.asarray(ql)
-    owner = np.asarray(route(st.router, qb, ql))
+    """Route once; per-shard owner masks as host bools (span
+    ``shard.route``: the router's launch and the owner fetch, a host
+    sync)."""
+    with obs.span("shard.route"):
+        qb = jnp.asarray(qb)
+        if qb.ndim != 2 or qb.shape[-1] != st.config.key_width:
+            got = "x".join(map(str, qb.shape))
+            raise ValueError(
+                f"query batch shape [{got}] does not match the tree's key "
+                f"width {st.config.key_width}: routing compares packed "
+                f"words, so keys must be zero-padded to exactly key_width "
+                f"bytes — build them with repro.core.keys.make_keyset("
+                f"keys, max_key_len={st.config.key_width})")
+        ql = jnp.asarray(ql)
+        owner = np.asarray(route(st.router, qb, ql))
     return qb, ql, owner
 
 
@@ -165,8 +175,8 @@ def lookup_batch(st: ShardedTree, qb, ql,
     """
     qb, ql, owner = _owner_masks(st, qb, ql)
     Bn = qb.shape[0]
-    vals = np.zeros((Bn,), dtype=np.asarray(
-        jnp.zeros((), st.config.val_dtype)).dtype)
+    vals = np.zeros((Bn,),
+                    dtype=jax.dtypes.canonicalize_dtype(st.config.val_dtype))
     found = np.zeros((Bn,), dtype=bool)
     degraded = np.zeros((Bn,), dtype=bool)
     pending = []
@@ -199,9 +209,10 @@ def lookup_batch(st: ShardedTree, qb, ql,
             v, rep = res
             hit += 1
         pending.append((sel, v, rep.found))     # async: combine later
-    for sel, v, f in pending:
-        vals[sel] = np.asarray(v)[sel]
-        found[sel] = np.asarray(f)[sel]
+    with obs.span("shard.combine"):
+        for sel, v, f in pending:
+            vals[sel] = np.asarray(v)[sel]
+            found[sel] = np.asarray(f)[sel]
     rep = ShardOpReport(found=found, conflicts=np.int32(0),
                         splits=np.int32(0), error=np.bool_(False),
                         owner=owner, shards_hit=hit,
@@ -318,14 +329,16 @@ def _combine(outcomes, owner, failed=None, skipped=0,
     splits = 0
     error = False
     conflicts = 0
-    for i, (sel, rep) in enumerate(outcomes):
-        found[sel] = np.asarray(rep.found)[sel]
-        splits += int(rep.splits)
-        error = error or bool(rep.error)
-        if i == 0:
-            # per-shard ops dedupe the FULL batch before the mask ANDs in,
-            # so any one report already carries the global conflict count
-            conflicts = int(rep.conflicts)
+    with obs.span("shard.combine"):     # each shard's outcome to the host
+        for i, (sel, rep) in enumerate(outcomes):
+            found[sel] = np.asarray(rep.found)[sel]
+            splits += int(rep.splits)
+            error = error or bool(rep.error)
+            if i == 0:
+                # per-shard ops dedupe the FULL batch before the mask ANDs
+                # in, so any one report already carries the global
+                # conflict count
+                conflicts = int(rep.conflicts)
     if failed is None:
         failed = np.zeros(owner.shape, dtype=bool)
     return ShardOpReport(found=found, conflicts=np.int32(conflicts),
@@ -377,7 +390,7 @@ def range_scan(st: ShardedTree, qb, ql, max_items: int = 64,
     Bn = qb.shape[0]
     L = st.config.key_width
     stride = st.kid_stride
-    vdt = np.asarray(jnp.zeros((), st.config.val_dtype)).dtype
+    vdt = jax.dtypes.canonicalize_dtype(st.config.val_dtype)
     out_kid = np.full((Bn, max_items), EMPTY, dtype=np.int64)
     out_val = np.zeros((Bn, max_items), dtype=vdt)
     emitted = np.zeros((Bn,), dtype=np.int32)
@@ -399,8 +412,9 @@ def range_scan(st: ShardedTree, qb, ql, max_items: int = 64,
             if not (owner > s).any():
                 break
             continue
-        sqb = np.where(active[:, None], qb_np, park_b[None, :])
-        sql = np.where(active, ql_np, park_l).astype(np.int32)
+        with obs.span("shard.route"):       # this shard's lanes, parked
+            sqb = np.where(active[:, None], qb_np, park_b[None, :])
+            sql = np.where(active, ql_np, park_l).astype(np.int32)
         dev = st.devices[s]
         res = _dispatch(
             st, s, "range_scan",
@@ -415,18 +429,17 @@ def range_scan(st: ShardedTree, qb, ql, max_items: int = 64,
             obs.event("shard.failed", op="range_scan", shard=s,
                       lanes=int(active.sum()))
             continue
-        kid_s, val_s, em_s, re_s = res
-        kid_s = np.asarray(kid_s)
-        val_s = np.asarray(val_s)
-        em_s = np.asarray(em_s)
-        take = np.where(active,
-                        np.minimum(em_s, max_items - emitted), 0)
-        ok = cols < take[:, None]          # emitted slots only: kid_s >= 0
-        dst = emitted[:, None] + cols
-        out_kid[rows[ok], dst[ok]] = kid_s[ok].astype(np.int64) + s * stride
-        out_val[rows[ok], dst[ok]] = val_s[ok]
-        emitted += take.astype(np.int32)
-        rearranged += np.where(active, np.asarray(re_s), 0).astype(np.int32)
+        with obs.span("shard.combine"):
+            kid_s, val_s, em_s, re_s = (np.asarray(x) for x in res)
+            take = np.where(active,
+                            np.minimum(em_s, max_items - emitted), 0)
+            ok = cols < take[:, None]      # emitted slots only: kid_s >= 0
+            dst = emitted[:, None] + cols
+            out_kid[rows[ok], dst[ok]] = (kid_s[ok].astype(np.int64)
+                                          + s * stride)
+            out_val[rows[ok], dst[ok]] = val_s[ok]
+            emitted += take.astype(np.int32)
+            rearranged += np.where(active, re_s, 0).astype(np.int32)
     return out_kid, out_val, emitted, rearranged, failed
 
 

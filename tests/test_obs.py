@@ -269,3 +269,104 @@ def test_shard_retry_and_degraded_events():
     assert len(retries) == 1 and retries[0]["shard"] == 0
     assert obs.get_metric("shard.retries", op="lookup").value == 1
     assert obs.event_summary().get("shard.degraded") is None
+
+
+# ------------------------------------- spans and scopes, telemetry off
+
+class _RecordedAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``; records names."""
+    names = []
+
+    def __init__(self, name, **kw):
+        self.names.append(name)
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+@pytest.mark.parametrize("on", [False, True], ids=["off", "on"])
+def test_span_annotates_its_own_name_either_way(monkeypatch, on):
+    """A span always enters a profiler annotation named exactly its name
+    (no labels, no dotted parent path); only with telemetry enabled does
+    it also record the ``span.<path>`` histogram."""
+    from repro.obs import trace as obs_trace
+    monkeypatch.setattr(obs_trace._profiler, "TraceAnnotation",
+                        _RecordedAnnotation)
+    monkeypatch.setattr(_RecordedAnnotation, "names", [])
+    if on:
+        obs.enable()
+    with obs.span("shard.dispatch", op="lookup", shard=1):
+        with obs.span("op.lookup"):
+            pass
+    assert _RecordedAnnotation.names == ["shard.dispatch", "op.lookup"]
+    m = obs.get_metric("span.shard.dispatch.op.lookup")
+    if on:
+        assert m is not None and m.count == 1
+    else:
+        assert m is None and obs.all_metrics() == []
+
+
+def _no_sync(*a, **k):
+    raise AssertionError("host sync with telemetry off")
+
+
+def test_ops_add_no_host_sync_with_telemetry_off(monkeypatch):
+    """With telemetry off no op drains its report: the bridge's
+    ``device_get`` and ``jax.device_get`` are never called, though every
+    op still runs under its span."""
+    import jax
+    from repro.obs import bridge
+    tree, base = _tree()
+    st, sbase = _sharded()
+    q = _keyset([int(x) for x in base[:32]])
+    sq = _keyset([int(x) for x in sbase[:32]])
+    vals = np.arange(32, dtype=np.int32)
+    monkeypatch.setattr(bridge, "_host", _no_sync)
+    monkeypatch.setattr(jax, "device_get", _no_sync)
+    B.lookup_batch(tree, q.bytes, q.lens)
+    t2, _ = B.update_batch(tree, q.bytes, q.lens, vals)
+    B.remove_batch(t2, q.bytes, q.lens)
+    B.range_scan(tree, q.bytes, q.lens, max_items=4)
+    B.rebuild(tree)
+    SH.lookup_batch(st, sq.bytes, sq.lens)
+    SH.update_batch(st, sq.bytes, sq.lens, vals)
+    SH.range_scan(st, sq.bytes, sq.lens, max_items=4)
+    obs.enable()            # the same op drains once telemetry is on
+    with pytest.raises(AssertionError, match="host sync"):
+        B.lookup_batch(tree, q.bytes, q.lens)
+
+
+@pytest.mark.parametrize("layout", ["tuple", "stacked"])
+def test_programs_carry_named_scopes_and_match_across_flag(layout):
+    """The lookup and update programs carry the ``descent`` and
+    ``leaf_probe`` scopes in their ``op_name`` metadata (per inner level
+    ``level_<l>`` under the tuple layout, one scan body when stacked), and
+    their lowered text, debug locations included, does not change with
+    the obs flag."""
+    import re
+
+    import jax.numpy as jnp
+    tree, base = _tree(n=120)
+    q = _keyset([int(x) for x in base[:32]])
+    qb, ql = jnp.asarray(q.bytes), jnp.asarray(q.lens)
+    vals = jnp.arange(32, dtype=jnp.int32)
+    eng = TraversalEngine("jnp", layout, collect_stats=False)
+    progs = {"_lookup_batch_jit": lambda: B._lookup_batch_jit.lower(
+                 tree, qb, ql, sibling_check=True, engine=eng),
+             "_update_batch_jit": lambda: B._update_batch_jit.lower(
+                 tree, qb, ql, vals, engine=eng)}
+    for name, lower in progs.items():
+        off = lower().as_text(debug_info=True)
+        obs.enable()
+        on = lower().as_text(debug_info=True)
+        obs.disable()
+        assert on == off, f"obs flag changed {name}"
+        scopes = set(re.findall(r'op_name="jit\(' + name + r'\)/([^"]*)"',
+                                lower().compile().as_text()))
+        assert any(s.startswith("descent/") for s in scopes), name
+        assert any(s.startswith("leaf_probe/") for s in scopes), name
+        per_level = any(s.startswith("descent/level_0/") for s in scopes)
+        assert per_level == (layout == "tuple"), name

@@ -236,6 +236,23 @@ def test_routed_mask_hook():
     assert np.asarray(rep3.found).tolist() == [True, True, False, False]
 
 
+def test_host_results_keep_device_value_dtype():
+    """The host arrays a routed lookup and scan combine into have the
+    dtype the shards' ``leaf_val`` holds: a 64-bit ``val_dtype`` with
+    64-bit JAX off gives 32-bit results, as the device arrays do."""
+    keys = list(range(0, 600, 3))
+    ks = K.make_keyset(keys, 8)
+    vals = np.arange(len(keys), dtype=np.int32)
+    cfg = TreeConfig.plan(max_keys=800, key_width=8, val_dtype=jnp.int64)
+    st = S.sharded_build(ks, vals, 4, cfg=cfg)
+    want = st.shards[0].arrays.leaf_val.dtype
+    v, rep = S.lookup_batch(st, ks.bytes, ks.lens)
+    assert rep.found.all() and v.dtype == want
+    assert (v == vals).all()
+    _, sv, em, _, _ = S.range_scan(st, ks.bytes[:2], ks.lens[:2], max_items=4)
+    assert sv.dtype == want and int(em[0]) == 4
+
+
 def test_rebalance_recovers_skew():
     """Skewed ingest concentrates keys in one shard; rebalance re-splits
     evenly, preserves every (key, value), and refreshes the router."""
