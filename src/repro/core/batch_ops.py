@@ -32,7 +32,7 @@ from .fbtree import (BIG, EMPTY, FBTree, Level, TreeArrays, _device_build_jit,
                      chunk_of_pos, chunk_start, recompute_inner_meta,
                      sort_pool, stack_levels)
 from .keys import compare_padded, fnv1a_tags, pack_words_j
-from .leaf import probe
+from .leaf import leaf_counts, leaf_rows, leaf_set, probe
 from .traverse import TraversalEngine, resolve_engine
 
 __all__ = [
@@ -217,8 +217,8 @@ def _update_batch_jit(tree: FBTree, qb, ql, vals,
     reported for every lane regardless of mask.
     """
     B = qb.shape[0]
-    a = tree.arrays
-    dump = a.leaf_occ.shape[0] - 1
+    a, ns = tree.arrays, tree.config.ns
+    dump = a.leaf_high.shape[0] - 1
     winners, conflicts = dedupe_last_wins(qb, ql, jnp.arange(B, dtype=jnp.int32))
     if mask is not None:
         winners = winners & mask
@@ -226,10 +226,10 @@ def _update_batch_jit(tree: FBTree, qb, ql, vals,
         tree, qb, ql, engine)
     do = winners & found
     li = jnp.where(do, leaf_ids, dump)
-    lv = a.leaf_val.at[li, slot].set(
-        jnp.where(do, vals.astype(a.leaf_val.dtype), a.leaf_val[li, slot]))
-    return tree.replace(leaf_val=lv), _report(found, bstats, lstats,
-                                              conflicts=conflicts)
+    lv = leaf_set(a.leaf_val_f, li, slot, vals.astype(a.leaf_val_f.dtype),
+                  ns, where=do)
+    return tree.replace(leaf_val_f=lv), _report(found, bstats, lstats,
+                                                conflicts=conflicts)
 
 
 def update_batch(tree: FBTree, qb, ql, vals,
@@ -248,8 +248,8 @@ def _remove_batch_jit(tree: FBTree, qb, ql,
     """Tombstone removal (slot cleared, version bumped). ``mask`` gates
     writes exactly as in :func:`update_batch` (routed-op hook)."""
     B = qb.shape[0]
-    a = tree.arrays
-    dump = a.leaf_occ.shape[0] - 1
+    a, ns = tree.arrays, tree.config.ns
+    dump = a.leaf_high.shape[0] - 1
     winners, conflicts = dedupe_last_wins(qb, ql, jnp.arange(B, dtype=jnp.int32))
     if mask is not None:
         winners = winners & mask
@@ -257,11 +257,10 @@ def _remove_batch_jit(tree: FBTree, qb, ql,
         tree, qb, ql, engine)
     do = winners & found
     li = jnp.where(do, leaf_ids, dump)
-    occ = a.leaf_occ.at[li, slot].set(jnp.where(do, False, a.leaf_occ[li, slot]))
-    kid = a.leaf_keyid.at[li, slot].set(
-        jnp.where(do, EMPTY, a.leaf_keyid[li, slot]))
+    occ = leaf_set(a.leaf_occ_f, li, slot, False, ns, where=do)
+    kid = leaf_set(a.leaf_keyid_f, li, slot, EMPTY, ns, where=do)
     ver = a.leaf_version.at[li].add(do.astype(jnp.int32))
-    return (tree.replace(leaf_occ=occ, leaf_keyid=kid, leaf_version=ver),
+    return (tree.replace(leaf_occ_f=occ, leaf_keyid_f=kid, leaf_version=ver),
             _report(found, bstats, lstats, conflicts=conflicts))
 
 
@@ -288,8 +287,8 @@ def _prepare_insert(tree: FBTree, qb, ql, vals,
     so they neither update in place nor append to the pool — the shard
     layer inserts each key only into its owning shard."""
     B = qb.shape[0]
-    a = tree.arrays
-    ldump = a.leaf_occ.shape[0] - 1
+    a, ns = tree.arrays, tree.config.ns
+    ldump = a.leaf_high.shape[0] - 1
     kdump = a.key_bytes.shape[0] - 1
     winners, conflicts = dedupe_last_wins(qb, ql, jnp.arange(B, dtype=jnp.int32))
     if mask is not None:
@@ -299,8 +298,8 @@ def _prepare_insert(tree: FBTree, qb, ql, vals,
 
     upd = winners & found
     li = jnp.where(upd, leaf_ids, ldump)
-    lv = a.leaf_val.at[li, slot].set(
-        jnp.where(upd, vals.astype(a.leaf_val.dtype), a.leaf_val[li, slot]))
+    lv = leaf_set(a.leaf_val_f, li, slot, vals.astype(a.leaf_val_f.dtype),
+                  ns, where=upd)
 
     is_new = winners & ~found
     offs = jnp.cumsum(is_new.astype(jnp.int32)) - 1
@@ -313,7 +312,7 @@ def _prepare_insert(tree: FBTree, qb, ql, vals,
     kt_new = a.key_tags.at[dst].set(
         jnp.where(is_new, fnv1a_tags(qb, ql), a.key_tags[dst]))
 
-    tree2 = tree.replace(leaf_val=lv, key_bytes=kb_new, key_lens=kl_new,
+    tree2 = tree.replace(leaf_val_f=lv, key_bytes=kb_new, key_lens=kl_new,
                          key_tags=kt_new, key_count=a.key_count + n_new)
     return tree2, kid_op, is_new, _report(found, bstats, lstats,
                                           conflicts=conflicts, error=err)
@@ -360,7 +359,7 @@ def _make_insert_round(cfg, max_ov: int, ins_cap: int,
     def round_fn(tree: FBTree, kid_op, pending, vals):
         a = tree.arrays
         B = kid_op.shape[0]
-        LC = a.leaf_occ.shape[0]
+        LC = a.leaf_high.shape[0]
         ldump = LC - 1
         qb = a.key_bytes[jnp.maximum(kid_op, 0)]
         ql = jnp.where(pending, a.key_lens[jnp.maximum(kid_op, 0)], 0)
@@ -377,23 +376,21 @@ def _make_insert_round(cfg, max_ov: int, ins_cap: int,
 
         cnt_leaf = jnp.zeros((LC,), jnp.int32).at[
             jnp.where(s_pending, s_leaf, ldump)].add(s_pending.astype(jnp.int32))
-        occ_cnt = a.leaf_occ.sum(-1).astype(jnp.int32)
+        occ_cnt = leaf_counts(a.leaf_occ_f, ns)
         fits_leaf = (occ_cnt + cnt_leaf) <= ns
 
         # ---------- fit path ----------
         s_fit = s_pending & fits_leaf[s_leaf]
-        occ_rows = a.leaf_occ[s_leaf]
+        occ_rows = leaf_rows(a.leaf_occ_f, s_leaf, ns)
         free_order = jnp.argsort(occ_rows.astype(jnp.int32), axis=-1, stable=True)
         slot = jnp.take_along_axis(free_order, jnp.minimum(rank, ns - 1)[:, None],
                                    axis=-1)[:, 0]
         li = jnp.where(s_fit, s_leaf, ldump)
-        sel = lambda new, old: jnp.where(s_fit, new, old)
-        leaf_keyid = a.leaf_keyid.at[li, slot].set(sel(s_kid, a.leaf_keyid[li, slot]))
-        leaf_val = a.leaf_val.at[li, slot].set(
-            sel(s_val.astype(a.leaf_val.dtype), a.leaf_val[li, slot]))
-        leaf_tags = a.leaf_tags.at[li, slot].set(
-            sel(a.key_tags[jnp.maximum(s_kid, 0)], a.leaf_tags[li, slot]))
-        leaf_occ = a.leaf_occ.at[li, slot].set(sel(True, a.leaf_occ[li, slot]))
+        put = lambda arr, new: leaf_set(arr, li, slot, new, ns, where=s_fit)
+        leaf_keyid = put(a.leaf_keyid_f, s_kid)
+        leaf_val = put(a.leaf_val_f, s_val.astype(a.leaf_val_f.dtype))
+        leaf_tags = put(a.leaf_tags_f, a.key_tags[jnp.maximum(s_kid, 0)])
+        leaf_occ = put(a.leaf_occ_f, True)
         leaf_version = a.leaf_version.at[li].add(s_fit.astype(jnp.int32))
         leaf_ordered = a.leaf_ordered.at[li].set(
             jnp.where(s_fit, False, a.leaf_ordered[li]))
@@ -416,19 +413,21 @@ def _make_insert_round(cfg, max_ov: int, ins_cap: int,
         done_sorted = done_sorted | s_proc
 
         ovl = jnp.where(ov_valid, ov_leaf, ldump)
+        vdt = a.leaf_val_f.dtype
         ws_kid = jnp.concatenate(
-            [a.leaf_keyid[ovl], jnp.full((max_ov, ins_cap), EMPTY, jnp.int32)], axis=1)
+            [leaf_rows(a.leaf_keyid_f, ovl, ns),
+             jnp.full((max_ov, ins_cap), EMPTY, jnp.int32)], axis=1)
         ws_val = jnp.concatenate(
-            [a.leaf_val[ovl], jnp.zeros((max_ov, ins_cap), a.leaf_val.dtype)], axis=1)
+            [leaf_rows(a.leaf_val_f, ovl, ns),
+             jnp.zeros((max_ov, ins_cap), vdt)], axis=1)
         ws_valid = jnp.concatenate(
-            [a.leaf_occ[ovl] & ov_valid[:, None],
+            [leaf_rows(a.leaf_occ_f, ovl, ns) & ov_valid[:, None],
              jnp.zeros((max_ov, ins_cap), bool)], axis=1)
         ri = jnp.where(s_proc, op_ovr, max_ov - 1)
         ci = jnp.where(s_proc, ns + jnp.minimum(rank, ins_cap - 1), 0)
         selp = lambda new, old: jnp.where(s_proc, new, old)
         ws_kid = ws_kid.at[ri, ci].set(selp(s_kid, ws_kid[ri, ci]))
-        ws_val = ws_val.at[ri, ci].set(
-            selp(s_val.astype(a.leaf_val.dtype), ws_val[ri, ci]))
+        ws_val = ws_val.at[ri, ci].set(selp(s_val.astype(vdt), ws_val[ri, ci]))
         ws_valid = ws_valid.at[ri, ci].set(selp(True, ws_valid[ri, ci]))
 
         rp = _repack_rows(a.key_bytes, a.key_lens, ws_kid, ws_val, ws_valid,
@@ -436,7 +435,7 @@ def _make_insert_round(cfg, max_ov: int, ins_cap: int,
 
         new_per_row = jnp.maximum(rp["n_chunks"] - 1, 0)
         new_base = a.leaf_count + jnp.cumsum(new_per_row) - new_per_row
-        err = (a.leaf_count + new_per_row.sum()) > ldump
+        err = (a.leaf_count + new_per_row.sum()) > cfg.leaf_cap
 
         dst_leaf = jnp.where(rp["chunk"] == 0, ovl[:, None],
                              new_base[:, None] + rp["chunk"] - 1)
@@ -444,16 +443,15 @@ def _make_insert_round(cfg, max_ov: int, ins_cap: int,
                              dst_leaf, ldump)
 
         clr = ovl
-        leaf_occ = leaf_occ.at[clr].set(
-            jnp.where(ov_valid[:, None], False, leaf_occ[clr]))
-        leaf_keyid = leaf_keyid.at[clr].set(
-            jnp.where(ov_valid[:, None], EMPTY, leaf_keyid[clr]))
+        clear = lambda arr, v: leaf_set(arr, clr, None, v, ns, where=ov_valid)
+        leaf_occ = clear(leaf_occ, False)
+        leaf_keyid = clear(leaf_keyid, EMPTY)
 
         fvalid = rp["valid"].reshape(-1)
         fl = jnp.where(fvalid, dst_leaf.reshape(-1), ldump)
         fsl = jnp.where(fvalid, jnp.clip(rp["slot"], 0, ns - 1).reshape(-1), ns - 1)
         fkid = rp["a"].reshape(-1)
-        w = lambda arr, val: arr.at[fl, fsl].set(jnp.where(fvalid, val, arr[fl, fsl]))
+        w = lambda arr, val: leaf_set(arr, fl, fsl, val, ns, where=fvalid)
         leaf_keyid = w(leaf_keyid, fkid)
         leaf_val = w(leaf_val, rp["b"].reshape(-1))
         leaf_tags = w(leaf_tags, a.key_tags[jnp.maximum(fkid, 0)])
@@ -481,8 +479,8 @@ def _make_insert_round(cfg, max_ov: int, ins_cap: int,
         n_splits = ov_valid.sum().astype(jnp.int32)
 
         arrays = a._replace(
-            leaf_keyid=leaf_keyid, leaf_val=leaf_val, leaf_tags=leaf_tags,
-            leaf_occ=leaf_occ, leaf_high=leaf_high, leaf_next=leaf_next,
+            leaf_keyid_f=leaf_keyid, leaf_val_f=leaf_val, leaf_tags_f=leaf_tags,
+            leaf_occ_f=leaf_occ, leaf_high=leaf_high, leaf_next=leaf_next,
             leaf_version=leaf_version, leaf_ordered=leaf_ordered,
             leaf_count=leaf_count)
 
@@ -729,14 +727,14 @@ def _range_scan_jnp(tree: FBTree, qb, ql, max_items: int,
     a = tree.arrays
     ns = tree.config.ns
     B = qb.shape[0]
-    dump = a.leaf_occ.shape[0] - 1
+    dump = a.leaf_high.shape[0] - 1
     cs = eng.collect_stats
     leaf_ids, _, _ = eng.traverse(tree, qb, ql)
     bidx = jnp.broadcast_to(jnp.arange(B)[:, None], (B, ns))
 
     # one scratch column at index max_items for masked scatter dumps
     out_kid = jnp.full((B, max_items + 1), EMPTY, jnp.int32)
-    out_val = jnp.zeros((B, max_items + 1), a.leaf_val.dtype)
+    out_val = jnp.zeros((B, max_items + 1), a.leaf_val_f.dtype)
     emitted = jnp.zeros((B,), jnp.int32)
 
     def emit_to(out_kid, out_val, emitted, kid, val, emit):
@@ -754,9 +752,9 @@ def _range_scan_jnp(tree: FBTree, qb, ql, max_items: int,
     # ---- hop 0 (peeled): start-key compare — key bytes gathered here and,
     # on later hops, only inside the dirty-leaf sort branch
     cur = leaf_ids
-    kid = a.leaf_keyid[cur]                       # [B, ns]
-    val = a.leaf_val[cur]
-    occ = a.leaf_occ[cur]
+    kid = leaf_rows(a.leaf_keyid_f, cur, ns)      # [B, ns]
+    val = leaf_rows(a.leaf_val_f, cur, ns)
+    occ = leaf_rows(a.leaf_occ_f, cur, ns)
     kb = a.key_bytes[jnp.maximum(kid, 0)]         # [B, ns, L]
     kl = jnp.where(occ, a.key_lens[jnp.maximum(kid, 0)], 0)
     dirty = ~a.leaf_ordered[cur]
@@ -791,9 +789,9 @@ def _range_scan_jnp(tree: FBTree, qb, ql, max_items: int,
         else:
             cur, emitted, out_kid, out_val = c
         active = cur != dump
-        kid = a.leaf_keyid[cur]
-        val = a.leaf_val[cur]
-        occ = a.leaf_occ[cur] & active[:, None]
+        kid = leaf_rows(a.leaf_keyid_f, cur, ns)
+        val = leaf_rows(a.leaf_val_f, cur, ns)
+        occ = leaf_rows(a.leaf_occ_f, cur, ns) & active[:, None]
         dirty = active & ~a.leaf_ordered[cur]
 
         def _sortedh(ops):
@@ -891,16 +889,17 @@ def _live_pool(tree: FBTree):
     invalid)``, the input of ``fbtree.sort_pool``. Every live key id sits in
     exactly one occupied leaf slot, so liveness and values scatter onto the
     pool, and the sort runs over its ``key_cap + 1`` rows rather than over
-    every leaf slot (``ns`` times ``leaf_cap + 1``, several times more at a
+    every leaf slot (``ns`` times ``leaf_len``, several times more at a
     planned capacity)."""
     a, KC = tree.arrays, tree.config.key_cap
-    occ = a.leaf_occ.reshape(-1)                  # [(leaf_cap+1) * ns]
+    # flattening a folded slot array gives the logical [LC, ns] order
+    occ = a.leaf_occ_f.reshape(-1)                # [leaf_len * ns]
     # free slots all write False/0 into the scratch row KC: the duplicate
     # writes agree, and no live key id reaches it
-    dst = jnp.where(occ, a.leaf_keyid.reshape(-1), KC)
+    dst = jnp.where(occ, a.leaf_keyid_f.reshape(-1), KC)
     live = jnp.zeros((KC + 1,), bool).at[dst].set(occ)
-    vals = jnp.zeros((KC + 1,), a.leaf_val.dtype).at[dst].set(
-        jnp.where(occ, a.leaf_val.reshape(-1), 0))
+    vals = jnp.zeros((KC + 1,), a.leaf_val_f.dtype).at[dst].set(
+        jnp.where(occ, a.leaf_val_f.reshape(-1), 0))
     return a.key_bytes, a.key_lens, a.key_tags, vals, ~live
 
 

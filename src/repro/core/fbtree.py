@@ -15,6 +15,9 @@ structure-of-arrays device representation (DESIGN.md §1):
     not key copies; here: indices into the key pool)
 * leaves: unsorted kv slots + occupancy bitmap + 1-byte hashtags + high key +
   sibling link + version word (insert/remove bump it; updates do *not* — §4.2).
+  The four slot arrays are stored lane-dense: ``leaf_fold`` leaves share one
+  128-lane row (DESIGN.md §1); ``TreeArrays.leaf_tags`` etc. are the
+  logical ``[LC, ns]`` views.
 
 Anchor convention: ``anchors[i]`` is the minimum key of ``children[i]``'s
 subtree; child ``i`` covers ``[anchors[i], anchors[i+1])`` and keys below
@@ -41,11 +44,18 @@ import numpy as np
 from . import keys as K
 
 __all__ = ["TreeConfig", "Level", "FBTree", "bulk_build", "tree_to_device",
-           "stack_levels", "chunk_start", "chunk_of_pos",
+           "leaf_fold", "stack_levels", "chunk_start", "chunk_of_pos",
            "recompute_inner_meta", "sharded_partition", "sort_pool"]
 
 EMPTY = np.int32(-1)
 BIG = jnp.int32(2**30)
+LANES = 128  # one TPU vector row
+
+
+def leaf_fold(ns: int) -> int:
+    """Leaves stored per row of the leaf slot arrays: ``128 // ns`` when
+    ``ns`` divides a 128-lane row, else 1 (DESIGN.md §1)."""
+    return LANES // ns if ns < LANES and LANES % ns == 0 else 1
 
 
 @dataclasses.dataclass(frozen=True)
@@ -70,7 +80,9 @@ class TreeConfig:
       nodes at the top (free pass-throughs, never billed in stats).
     * ``leaf_cap`` / ``level_caps`` / ``key_cap``  allocation watermark caps;
       arrays are padded to ``cap + 1`` rows, the extra row being the scratch
-      row masked scatters dump into (DESIGN.md §1).
+      row masked scatters dump into (DESIGN.md §1). Per-leaf arrays hold
+      :attr:`leaf_len` leaves: ``leaf_cap + 1`` rounded up to a multiple of
+      :attr:`leaf_fold`, the scratch leaf last.
     * ``val_dtype``   leaf value dtype.
     * ``stacked``     default descent layout for the traversal engine:
       False = per-level tuple (Python loop), True = stacked
@@ -125,6 +137,19 @@ class TreeConfig:
         if self.key_cap < 1:
             bad(f"key_cap must be >= 1, got {self.key_cap}")
 
+    @property
+    def leaf_fold(self) -> int:
+        """Leaves per stored row of the leaf slot arrays (:func:`leaf_fold`)."""
+        return leaf_fold(self.ns)
+
+    @property
+    def leaf_len(self) -> int:
+        """Leaves every per-leaf array holds: ``leaf_cap`` plus the scratch
+        leaf, rounded up to a multiple of ``leaf_fold``; the scratch leaf is
+        the last one."""
+        f = self.leaf_fold
+        return -(-(self.leaf_cap + 1) // f) * f
+
     @staticmethod
     def plan(max_keys: int, key_width: int, ns: int = 64, fs: int = 4,
              leaf_fill: int = 48, inner_fill: int = 48,
@@ -170,11 +195,23 @@ class Level(NamedTuple):
     count: jnp.ndarray     # int32 scalar — allocation watermark
 
 
+LEAF_SLOT_VIEWS = ("leaf_tags", "leaf_keyid", "leaf_val", "leaf_occ")
+
+
 class TreeArrays(NamedTuple):
     """All tree state as a flat pytree of device arrays.
 
-    Shapes below use ``KC = key_cap + 1``, ``LC = leaf_cap + 1`` (the ``+1``
-    is the scratch row, DESIGN.md §1), ``L = key_width``, ``ns`` slots.
+    Shapes below use ``KC = key_cap + 1``, ``LC = leaf_len`` (``leaf_cap``
+    plus the scratch leaf, rounded up to a multiple of ``f = leaf_fold``;
+    DESIGN.md §1), ``L = key_width``, ``ns`` slots.
+
+    The leaf slot arrays are stored folded, ``[LC // f, f * ns]``: leaf
+    ``i`` is row ``i // f``, lanes ``(i % f) * ns`` to ``+ ns`` — the
+    logical ``[LC, ns]`` array reshaped, so a 64-slot leaf store fills
+    128-lane rows. Hot paths read and write them through
+    ``core.leaf.leaf_rows``/``leaf_at``/``leaf_set``; ``leaf_tags``,
+    ``leaf_keyid``, ``leaf_val`` and ``leaf_occ`` are read-only logical
+    views for cold paths (``FBTree.replace`` folds them back).
 
     Invariants the parity/property suites check
     (``tests/test_traverse_parity.py``, ``tests/test_tree_ops.py``):
@@ -197,15 +234,34 @@ class TreeArrays(NamedTuple):
     key_count: jnp.ndarray   # int32 scalar
     levels: Tuple[Level, ...]
     stacked: Level           # same levels, stacked+padded to [n_levels, C_max, ...]
-    leaf_tags: jnp.ndarray   # uint8 [LC, ns]
-    leaf_keyid: jnp.ndarray  # int32 [LC, ns] (-1 empty)
-    leaf_val: jnp.ndarray    # val_dtype [LC, ns]
-    leaf_occ: jnp.ndarray    # bool [LC, ns]
+    leaf_tags_f: jnp.ndarray   # uint8 [LC // f, f * ns]
+    leaf_keyid_f: jnp.ndarray  # int32 [LC // f, f * ns] (-1 empty)
+    leaf_val_f: jnp.ndarray    # val_dtype [LC // f, f * ns]
+    leaf_occ_f: jnp.ndarray    # bool [LC // f, f * ns]
     leaf_high: jnp.ndarray   # int32 [LC] key id, -1 = +inf
     leaf_next: jnp.ndarray   # int32 [LC]
     leaf_version: jnp.ndarray  # int32 [LC]
     leaf_ordered: jnp.ndarray  # bool [LC]
     leaf_count: jnp.ndarray    # int32 scalar
+
+    def _unfold(self, folded):
+        return folded.reshape(self.leaf_high.shape[0], -1)
+
+    @property
+    def leaf_tags(self):     # uint8 [LC, ns]
+        return self._unfold(self.leaf_tags_f)
+
+    @property
+    def leaf_keyid(self):    # int32 [LC, ns]
+        return self._unfold(self.leaf_keyid_f)
+
+    @property
+    def leaf_val(self):      # val_dtype [LC, ns]
+        return self._unfold(self.leaf_val_f)
+
+    @property
+    def leaf_occ(self):      # bool [LC, ns]
+        return self._unfold(self.leaf_occ_f)
 
 
 @jax.tree_util.register_pytree_node_class
@@ -225,16 +281,23 @@ class FBTree:
 
     # convenience accessors
     def __getattr__(self, name):
-        if name in TreeArrays._fields:
+        if name in TreeArrays._fields or name in LEAF_SLOT_VIEWS:
             return getattr(self.arrays, name)
         raise AttributeError(name)
 
     def replace(self, **kw) -> "FBTree":
-        return FBTree(self.config, self.arrays._replace(**kw))
+        """``arrays._replace``; a logical ``[LC, ns]`` leaf slot array given
+        under its view name (``leaf_val=`` …) is folded into storage."""
+        a = self.arrays
+        for name in LEAF_SLOT_VIEWS:
+            if name in kw:
+                kw[name + "_f"] = jnp.reshape(
+                    kw.pop(name), getattr(a, name + "_f").shape)
+        return FBTree(self.config, a._replace(**kw))
 
     @property
     def n_keys_live(self) -> int:
-        return int(jnp.sum(self.arrays.leaf_occ))
+        return int(jnp.sum(self.arrays.leaf_occ_f))
 
 
 def stack_levels(levels: Tuple[Level, ...]) -> Level:
@@ -436,7 +499,7 @@ def bulk_build(cfg: TreeConfig, ks: K.KeySet, vals: np.ndarray,
     fill = cfg.leaf_fill
     n_leaves = max(1, -(-n // fill))
     assert n_leaves <= cfg.leaf_cap, "leaf_cap exceeded"
-    LC = cfg.leaf_cap + 1  # + scratch row
+    LC = cfg.leaf_len  # + scratch row, a multiple of leaf_fold
     leaf_tags = np.zeros((LC, ns), dtype=np.uint8)
     leaf_keyid = np.full((LC, ns), EMPTY, dtype=np.int32)
     leaf_val = np.zeros((LC, ns), dtype=np.asarray(vals).dtype)
@@ -515,15 +578,16 @@ def bulk_build(cfg: TreeConfig, ks: K.KeySet, vals: np.ndarray,
 
     ktags = np.zeros((cfg.key_cap + 1,), dtype=np.uint8)
     ktags[:n] = tags_all
+    fold = lambda x: jnp.asarray(x.reshape(LC // cfg.leaf_fold, -1))
     arrays = TreeArrays(
         key_bytes=jnp.asarray(kb), key_lens=jnp.asarray(kl),
         key_tags=jnp.asarray(ktags),
         key_count=jnp.asarray(np.int32(n)),
         levels=tuple(levels),
         stacked=stack_levels(tuple(levels)),
-        leaf_tags=jnp.asarray(leaf_tags), leaf_keyid=jnp.asarray(leaf_keyid),
-        leaf_val=jnp.asarray(leaf_val).astype(cfg.val_dtype),
-        leaf_occ=jnp.asarray(leaf_occ),
+        leaf_tags_f=fold(leaf_tags), leaf_keyid_f=fold(leaf_keyid),
+        leaf_val_f=fold(leaf_val).astype(cfg.val_dtype),
+        leaf_occ_f=fold(leaf_occ),
         leaf_high=jnp.asarray(leaf_high), leaf_next=jnp.asarray(leaf_next),
         leaf_version=jnp.zeros((LC,), jnp.int32),
         leaf_ordered=jnp.asarray(np.arange(LC) < n_leaves),
@@ -556,19 +620,28 @@ def _device_build_from_sorted(cfg: TreeConfig, kb, kl, ktags, vals, n):
     """
     ns, fs, L = cfg.ns, cfg.fs, cfg.key_width
     KC = cfg.key_cap
-    LC = cfg.leaf_cap + 1
+    LC, f = cfg.leaf_len, cfg.leaf_fold
     n = jnp.asarray(n, jnp.int32)
     lane = jnp.arange(ns, dtype=jnp.int32)
 
     # ---- leaves: balanced chunking of the sorted key run ----
     n_leaves = jnp.maximum(1, -(-n // jnp.int32(cfg.leaf_fill)))
     base, rem = n // n_leaves, n % n_leaves
+    start = lambda l: chunk_start(l, base, rem)
+    size = lambda l: base + (l < rem).astype(jnp.int32)
     li = jnp.arange(LC, dtype=jnp.int32)
-    lstart = chunk_start(li, base, rem)            # [LC]
-    lsize = base + (li < rem).astype(jnp.int32)
-    lexists = li < n_leaves
-    pos = lstart[:, None] + lane[None, :]          # key id at (leaf, slot)
-    lvalid = lexists[:, None] & (lane[None, :] < lsize[:, None]) & (pos < n)
+    lstart, lsize, lexists = start(li), size(li), li < n_leaves   # [LC]
+    # the slot grid in folded shape [LC // f, f * ns] (DESIGN.md §1): each
+    # lane's leaf and slot come from iotas, so nothing [LC, ns] is built
+    if f == 1:
+        gstart, gsize, gexists = lstart[:, None], lsize[:, None], lexists[:, None]
+        slot = lane[None, :]
+    else:
+        g = jnp.arange(f * ns, dtype=jnp.int32)
+        gl = jnp.arange(LC // f, dtype=jnp.int32)[:, None] * f + g // ns
+        gstart, gsize, gexists, slot = start(gl), size(gl), gl < n_leaves, g % ns
+    pos = gstart + slot                            # key id at (leaf, slot)
+    lvalid = gexists & (slot < gsize) & (pos < n)
     pos_safe = jnp.clip(pos, 0, KC)
     leaf_keyid = jnp.where(lvalid, pos, EMPTY)
     leaf_val = jnp.where(lvalid, vals[pos_safe], 0).astype(cfg.val_dtype)
@@ -624,8 +697,8 @@ def _device_build_from_sorted(cfg: TreeConfig, kb, kl, ktags, vals, n):
         key_count=n,
         levels=levels,
         stacked=stack_levels(levels),
-        leaf_tags=leaf_tags, leaf_keyid=leaf_keyid, leaf_val=leaf_val,
-        leaf_occ=lvalid,
+        leaf_tags_f=leaf_tags, leaf_keyid_f=leaf_keyid, leaf_val_f=leaf_val,
+        leaf_occ_f=lvalid,
         leaf_high=leaf_high, leaf_next=leaf_next,
         leaf_version=jnp.zeros((LC,), jnp.int32),
         leaf_ordered=lexists,

@@ -6,10 +6,69 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
-from .fbtree import FBTree
+from .fbtree import FBTree, leaf_fold
 from .keys import fnv1a_tags
 
-__all__ = ["LeafStats", "probe", "verify_candidates", "find_free_slots"]
+__all__ = ["LeafStats", "probe", "verify_candidates", "find_free_slots",
+           "leaf_rows", "leaf_at", "leaf_set", "leaf_counts"]
+
+
+# --------------------------------------------------------------------------
+# folded leaf slot arrays (DESIGN.md §1): leaf i is row i // f, lanes
+# (i % f) * ns .. + ns of a [LC // f, f * ns] array, f = leaf_fold(ns)
+# --------------------------------------------------------------------------
+
+def leaf_rows(arr, leaf_ids, ns: int):
+    """Slot rows ``[..., ns]`` of leaves ``leaf_ids`` from a folded array:
+    gather the stored row, keep the leaf's lane group."""
+    f = leaf_fold(ns)
+    if f == 1:
+        return arr[leaf_ids]
+    rows = arr[leaf_ids // f]                     # [..., f * ns]
+    group = (leaf_ids % f)[..., None]
+    out = rows[..., :ns]
+    for g in range(1, f):
+        out = jnp.where(group == g, rows[..., g * ns:(g + 1) * ns], out)
+    return out
+
+
+def leaf_at(arr, leaf_ids, slot, ns: int):
+    """Slot ``slot`` of leaves ``leaf_ids`` from a folded array."""
+    f = leaf_fold(ns)
+    if f == 1:
+        return arr[leaf_ids, slot]
+    return arr[leaf_ids // f, (leaf_ids % f) * ns + slot]
+
+
+def leaf_set(arr, leaf_ids, slot, v, ns: int, where=None):
+    """Scatter ``v`` into slot ``slot`` of leaves ``leaf_ids`` of a folded
+    array; ``slot=None`` writes whole leaves (``v`` is ``[..., ns]``).
+    Lanes where ``where`` is False write back the value they address, so
+    masked lanes aimed at the scratch leaf leave it as it was. Only the
+    addressed leaves' lanes change, never a neighbour in the same row."""
+    if where is not None:
+        if slot is None:
+            v = jnp.where(where[..., None], v, leaf_rows(arr, leaf_ids, ns))
+        else:
+            v = jnp.where(where, v, leaf_at(arr, leaf_ids, slot, ns))
+    f = leaf_fold(ns)
+    if slot is None:
+        if f == 1:
+            return arr.at[leaf_ids].set(v)
+        leaf_ids = leaf_ids[..., None]
+        slot = jnp.arange(ns, dtype=jnp.int32)
+    if f == 1:
+        return arr.at[leaf_ids, slot].set(v)
+    return arr.at[leaf_ids // f, (leaf_ids % f) * ns + slot].set(v)
+
+
+def leaf_counts(occ, ns: int):
+    """Occupied slots per leaf, ``int32 [LC]``, from the folded ``occ``."""
+    f = leaf_fold(ns)
+    if f == 1:
+        return occ.sum(-1).astype(jnp.int32)
+    per_group = [occ[:, g * ns:(g + 1) * ns].sum(-1) for g in range(f)]
+    return jnp.stack(per_group, axis=1).reshape(-1).astype(jnp.int32)
 
 
 class LeafStats(NamedTuple):
@@ -78,14 +137,15 @@ def probe(tree: FBTree, leaf_ids: jnp.ndarray, qb: jnp.ndarray, ql: jnp.ndarray,
     ``stats=None`` (the candidate mask itself is load-bearing and stays).
     """
     a = tree.arrays
-    ns = a.leaf_tags.shape[-1]
+    ns = tree.config.ns
     qtag = fnv1a_tags(qb, ql)
-    tags = a.leaf_tags[leaf_ids]              # [B, ns]
-    occ = a.leaf_occ[leaf_ids]
+    tags = leaf_rows(a.leaf_tags_f, leaf_ids, ns)     # [B, ns]
+    occ = leaf_rows(a.leaf_occ_f, leaf_ids, ns)
     cand = (tags == qtag[:, None]) & occ
-    kid = a.leaf_keyid[leaf_ids]              # [B, ns]
+    kid = leaf_rows(a.leaf_keyid_f, leaf_ids, ns)     # [B, ns]
     found, slot = verify_candidates(a, cand, kid, qb, ql)
-    val = jnp.take_along_axis(a.leaf_val[leaf_ids], slot[:, None], axis=-1)[:, 0]
+    val = jnp.take_along_axis(leaf_rows(a.leaf_val_f, leaf_ids, ns),
+                              slot[:, None], axis=-1)[:, 0]
     val = jnp.where(found, val, 0)
     if not collect_stats:
         return found, slot, val, None

@@ -4,7 +4,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from repro.core.keys import fnv1a_tags
-from repro.core.leaf import LeafStats, verify_candidates
+from repro.core.leaf import LeafStats, leaf_rows, verify_candidates
 
 from .. import interpret_mode
 from .kernel import DEFAULT_TILE_B, leaf_probe_kernel
@@ -35,16 +35,17 @@ def probe_pallas(tree, leaf_ids, qb, ql, use_pallas: bool = True,
     """Drop-in replacement for core.leaf.probe using the kernel for the
     hashtag filter; exact verification gathers only candidate slots."""
     a = tree.arrays
-    ns = a.leaf_tags.shape[-1]
+    ns = tree.config.ns
     qtag = fnv1a_tags(qb, ql)
-    tags = a.leaf_tags[leaf_ids]
-    occ = a.leaf_occ[leaf_ids]
+    tags = leaf_rows(a.leaf_tags_f, leaf_ids, ns)
+    occ = leaf_rows(a.leaf_occ_f, leaf_ids, ns)
     cand_u8, first, count = leaf_probe(tags, occ, qtag[:, None],
                                        use_pallas=use_pallas)
     cand = cand_u8 != 0
-    kid = a.leaf_keyid[leaf_ids]
+    kid = leaf_rows(a.leaf_keyid_f, leaf_ids, ns)
     found, slot = verify_candidates(a, cand, kid, qb, ql)
-    val = jnp.take_along_axis(a.leaf_val[leaf_ids], slot[:, None], axis=-1)[:, 0]
+    val = jnp.take_along_axis(leaf_rows(a.leaf_val_f, leaf_ids, ns),
+                              slot[:, None], axis=-1)[:, 0]
     val = jnp.where(found, val, 0)
     if not collect_stats:
         return found, slot, val, None

@@ -4,11 +4,15 @@ The TPU compiler is installed here and compiles for a described topology,
 so what Mosaic or XLA would refuse on the chip — an unsupported vector op in
 a Pallas kernel, a layout it cannot produce, more VMEM than a kernel may
 use — fails here at no chip time. Nothing runs: these tests say nothing
-about results or speed.
+about results or speed, though the compiled text shows where the compiler
+copies or loops over a whole array.
 
 This is the only test file that describes the topology, and it does so
 inside a fixture, so that one test worker loads the TPU library.
 """
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -29,6 +33,9 @@ from repro.kernels.leaf_probe.kernel import leaf_probe_kernel
 NS = 128            # the TPU-natural node width: one full lane row
 KEY_W = 24          # YCSB keys
 TREE_KEYS = 1 << 16  # compiles in seconds; shapes scale, code does not
+# the one-chip YCSB deployment (ns 64): at this size the compiler places
+# the leaf store in HBM and picks its layouts as it does on the chip
+YCSB_KEYS = (1 << 25) + (1 << 18)
 
 
 @pytest.fixture(scope="module")
@@ -56,11 +63,7 @@ def _sds(one_chip, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
 
-@pytest.fixture(scope="module")
-def tree(one_chip):
-    """Shapes (not arrays) of an ns=128 tree, placed on the described
-    chip."""
-    cfg = TreeConfig.plan(max_keys=TREE_KEYS, key_width=KEY_W, ns=NS)
+def _tree_shapes(one_chip, cfg):
     R = cfg.key_cap + 1
     arrays, _ = jax.eval_shape(
         lambda kb, kl, kt, v, n: _device_build_jit(
@@ -72,6 +75,21 @@ def tree(one_chip):
         jax.ShapeDtypeStruct((), jnp.int32))
     return FBTree(cfg, jax.tree_util.tree_map(
         lambda s: _sds(one_chip, s.shape, s.dtype), arrays))
+
+
+@pytest.fixture(scope="module")
+def tree(one_chip):
+    """Shapes (not arrays) of an ns=128 tree, placed on the described
+    chip."""
+    return _tree_shapes(one_chip, TreeConfig.plan(
+        max_keys=TREE_KEYS, key_width=KEY_W, ns=NS))
+
+
+@pytest.fixture(scope="module")
+def tree64(one_chip):
+    """Shapes of the ns=64 YCSB tree at its deployed size."""
+    return _tree_shapes(one_chip, TreeConfig.plan(
+        max_keys=YCSB_KEYS, key_width=KEY_W, ns=64))
 
 
 def _queries(one_chip, b):
@@ -103,6 +121,54 @@ def test_index_ops_compile_for_v5e(one_chip, tree, op, monkeypatch):
     text = low.compile().as_text()
     # the Pallas engine's levels are Mosaic kernels, not interpreted XLA
     assert ("tpu_custom_call" in text) == (op == "lookup_pallas")
+
+
+LEAF_PARAM = re.compile(
+    r'%([\w.\-]+) = \w+\[([\d,]+)\](\{[^}]*\}) parameter\(\d+\).*'
+    r'op_name="tree\[0\]\.leaf_(?:tags|keyid|val|occ)')
+SHAPE = re.compile(r"\b[a-z]+\d*\[([\d,]+)\]")
+
+
+def _elements(dims: str) -> int:
+    return math.prod(int(d) for d in dims.split(","))
+
+
+@pytest.mark.parametrize("op", ["lookup", "update"])
+def test_ns64_leaf_store_is_used_in_place_on_v5e(one_chip, tree64, op):
+    """An ns=64 tree's leaf slot arrays reach the program in a layout its
+    gathers and scatters use as they are: no ``copy`` in the entry
+    computation takes a leaf-store parameter whole into another layout (a
+    relayout of the whole store on every call), and no ``while`` carries an
+    operand the size of the flattened store (a point scatter run as a
+    serial loop over it). A copy in the parameter's own layout — the
+    undonated update's output — is not a relayout."""
+    qb, ql = _queries(one_chip, 4096)
+    if op == "lookup":
+        low = B._lookup_batch_jit.lower(tree64, qb, ql, True, STATS_OFF)
+    else:
+        low = B._update_batch_jit.lower(tree64, qb, ql,
+                                        _sds(one_chip, (4096,), jnp.int32),
+                                        STATS_OFF)
+    text = low.compile().as_text()
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}\n")]
+    params = {name: layout for name, _, layout in LEAF_PARAM.findall(entry)}
+    assert len(params) == 4, params
+    slots = {_elements(dims) for _, dims, _ in LEAF_PARAM.findall(entry)}
+    assert len(slots) == 1, slots
+    names = "|".join(map(re.escape, params))
+    whole_copy = re.compile(r"= \w+\[[\d,]+\](\{[^}]*\})\S* "
+                            rf"copy\(%({names})\)")
+    relayouts = [ln.strip()[:120] for ln in entry.splitlines()
+                 for m in [whole_copy.search(ln)]
+                 if m and m.group(1).split(":")[0]
+                 != params[m.group(2)].split(":")[0]]
+    assert not relayouts, relayouts
+    loops = [ln.strip()[:120] for ln in text.splitlines()
+             if " while(" in ln and any(
+                 _elements(dims) in slots
+                 for dims in SHAPE.findall(ln.split(" while(")[0]))]
+    assert not loops, loops
 
 
 def test_build_and_rebuild_sort_compiles_for_v5e(one_chip, tree):
