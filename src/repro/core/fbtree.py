@@ -745,12 +745,20 @@ def _sort_raw(cfg: TreeConfig, qb, ql, vals):
             pad(K.fnv1a_tags(qb, ql), jnp.uint8), pad(vals, cfg.val_dtype))
 
 
-def _bulk_build_device(cfg: TreeConfig, ks: K.KeySet, vals) -> FBTree:
-    """``bulk_build(device=True)`` body: device sort + jitted build core."""
+def _launch_device_build(cfg: TreeConfig, ks: K.KeySet, vals):
+    """Dispatch the device sort and the jitted build core on the default
+    device without waiting for them: ``(arrays, err)``, both unfetched.
+    ``repro.shard.sharded_build`` launches every shard's build through it
+    before the first host sync."""
     kb, kl, ktags, vv = _sort_raw(cfg, jnp.asarray(ks.bytes),
                                   jnp.asarray(ks.lens), jnp.asarray(vals))
-    arrays, err = _device_build_jit(cfg=cfg, kb=kb, kl=kl, ktags=ktags,
-                                    vals=vv, n=jnp.int32(ks.n))
+    return _device_build_jit(cfg=cfg, kb=kb, kl=kl, ktags=ktags, vals=vv,
+                             n=jnp.int32(ks.n))
+
+
+def _bulk_build_device(cfg: TreeConfig, ks: K.KeySet, vals) -> FBTree:
+    """``bulk_build(device=True)`` body: device sort + jitted build core."""
+    arrays, err = _launch_device_build(cfg, ks, vals)
     # _check_capacity already vetted n host-side; err re-validates on device
     if bool(err):  # pragma: no cover - unreachable after _check_capacity
         raise RuntimeError("bulk_build(device=True): capacity exceeded")

@@ -11,7 +11,12 @@ shard can absorb the whole key set before a ``rebalance`` — skew-safe, at
 S× pool memory; pass each shard's share, ``max_keys / n_shards`` plus
 headroom, when memory matters, as a deployment filling its chips does).
 Each shard is built on its own device, so no device carries another
-shard's build temporaries.
+shard's build temporaries. With ``device=True`` every shard's sort and
+build is launched before any host sync (the capacity flags are read only
+once all are queued), so the shards' builds overlap across chips instead
+of running one after another; each shard is bit-identical to a one-tree
+``bulk_build(device=True)`` of its slice. The build program compiles once
+per chip: its executable is bound to the device it runs on.
 """
 from __future__ import annotations
 
@@ -21,7 +26,9 @@ import jax
 import numpy as np
 
 from repro.core import keys as K
-from repro.core.fbtree import TreeConfig, bulk_build, sharded_partition
+from repro.core.fbtree import (FBTree, TreeConfig, _check_capacity,
+                                _launch_device_build, bulk_build,
+                                sharded_partition)
 
 from .mesh import make_shard_mesh, place_shard, shard_devices
 from .router import make_router
@@ -91,13 +98,24 @@ def sharded_build(ks: K.KeySet, vals, n_shards: int,
     if mesh == "auto":
         mesh = make_shard_mesh(n_shards)
     devices = shard_devices(mesh, n_shards)
-    shards = []
+    for pks, _ in parts:            # every shard vetted before any launch
+        _check_capacity(cfg, pks.n)
+    trees, errs = [], []
     for (pks, pvals), dev in zip(parts, devices):
         # build where the shard lives: the device build's inputs and
         # temporaries land on `dev` (None: the default device), and placing
-        # it is then a no-op
+        # it is then a no-op. A device build is only launched here, so
+        # every shard's build is queued before the first host sync below.
         with jax.default_device(dev):
-            t = bulk_build(cfg, pks, np.asarray(pvals), device=device)
-        shards.append(place_shard(t, dev))
-    return ShardedTree(shards=tuple(shards), router=make_router(split_keys),
+            if device:
+                arrays, err = _launch_device_build(cfg, pks,
+                                                   np.asarray(pvals))
+                trees.append(FBTree(cfg, arrays))
+                errs.append(err)
+            else:
+                trees.append(bulk_build(cfg, pks, np.asarray(pvals)))
+    if any(bool(err) for err in errs):  # pragma: no cover - as bulk_build
+        raise RuntimeError("bulk_build(device=True): capacity exceeded")
+    shards = tuple(place_shard(t, dev) for t, dev in zip(trees, devices))
+    return ShardedTree(shards=shards, router=make_router(split_keys),
                        devices=devices, mesh=mesh)

@@ -37,7 +37,13 @@ under a span, none inside another — ``shard.route`` (the router's launch
 and the owner fetch; in a scan, also each shard's parked lanes),
 ``shard.dispatch`` (one shard's launch, retries included; the shard op's
 own ``op.<name>`` span nests in it) and ``shard.combine`` (per-shard
-results pulled to the host and merged by owner).
+results pulled to the host and merged by owner). While ``repro.obs`` is
+enabled, counters measure the full-width dispatch:
+``shard.lanes{op, shard}`` (lanes the router gave each shard, counted from
+the owners the route already fetched), ``shard.launches{op}`` and
+``shard.launch_lanes{op}`` (lanes the launched shard programs compute:
+the batch width per launch). ``launch_lanes`` over the lanes served is
+the masked overcompute.
 """
 from __future__ import annotations
 
@@ -103,10 +109,10 @@ def _put(x, dev):
     return x if dev is None else jax.device_put(x, dev)
 
 
-def _owner_masks(st: ShardedTree, qb, ql):
+def _owner_masks(st: ShardedTree, qb, ql, opname: str):
     """Route once; per-shard owner masks as host bools (span
     ``shard.route``: the router's launch and the owner fetch, a host
-    sync)."""
+    sync). Counts ``shard.lanes`` per shard while telemetry is on."""
     with obs.span("shard.route"):
         qb = jnp.asarray(qb)
         if qb.ndim != 2 or qb.shape[-1] != st.config.key_width:
@@ -119,12 +125,18 @@ def _owner_masks(st: ShardedTree, qb, ql):
                 f"keys, max_key_len={st.config.key_width})")
         ql = jnp.asarray(ql)
         owner = np.asarray(route(st.router, qb, ql))
+    if obs.enabled():
+        per_shard = np.bincount(owner, minlength=st.n_shards)
+        for s, n in enumerate(per_shard):
+            obs.counter("shard.lanes", op=opname, shard=s).inc(int(n))
     return qb, ql, owner
 
 
 def _dispatch(st: ShardedTree, s: int, opname: str, call,
-              faults: Optional[FaultPlan], retry: Optional[RetryPolicy]):
-    """Launch one shard-local op through the fault layer.
+              faults: Optional[FaultPlan], retry: Optional[RetryPolicy],
+              lanes: int):
+    """Launch one shard-local op of ``lanes`` lanes through the fault
+    layer.
 
     Returns the op result, or None when the shard cannot be reached: the
     site ``shard.dispatch.<opname>`` fires per attempt; ShardDropped is
@@ -145,7 +157,10 @@ def _dispatch(st: ShardedTree, s: int, opname: str, call,
                 if faults is not None:
                     faults.fire(f"shard.dispatch.{opname}", shard=s,
                                 attempt=attempt)
-                return call()
+                res = call()
+                obs.counter("shard.launches", op=opname).inc()
+                obs.counter("shard.launch_lanes", op=opname).inc(lanes)
+                return res
             except ShardDropped:
                 obs.counter("shard.retries", op=opname).inc()
                 obs.event("shard.retry", op=opname, shard=s,
@@ -173,7 +188,7 @@ def lookup_batch(st: ShardedTree, qb, ql,
     barrier, possibly stale) rather than failed; reads prefer staleness
     over unavailability. ``report.failed`` stays all-False for lookups.
     """
-    qb, ql, owner = _owner_masks(st, qb, ql)
+    qb, ql, owner = _owner_masks(st, qb, ql, "lookup")
     Bn = qb.shape[0]
     vals = np.zeros((Bn,),
                     dtype=jax.dtypes.canonicalize_dtype(st.config.val_dtype))
@@ -193,7 +208,7 @@ def lookup_batch(st: ShardedTree, qb, ql,
             st, s, "lookup",
             lambda: B.lookup_batch(t, _put(qb, dev), _put(ql, dev),
                                    engine=engine),
-            faults, retry)
+            faults, retry, Bn)
         if res is None:
             # degrade: the snapshot replica is reachable by construction
             # (it lives with the router, not behind the downed dispatch)
@@ -247,7 +262,7 @@ def _routed_mutation(st: ShardedTree, owner, opname, run_one, faults,
         def call(t=t, sel=sel, dev=dev):
             mask = _put(jnp.asarray(sel), dev)
             return run_one(t, mask, dev)
-        res = _dispatch(st, s, opname, call, faults, retry)
+        res = _dispatch(st, s, opname, call, faults, retry, owner.shape[0])
         if res is None:
             failed |= sel
             dropped.append(s)
@@ -266,7 +281,7 @@ def update_batch(st: ShardedTree, qb, ql, vals,
                  faults: Optional[FaultPlan] = None,
                  retry: Optional[RetryPolicy] = None):
     """Routed blind update. Returns ``(ShardedTree', report)``."""
-    qb, ql, owner = _owner_masks(st, qb, ql)
+    qb, ql, owner = _owner_masks(st, qb, ql, "update")
     vals = jnp.asarray(vals)
 
     def run_one(t, mask, dev):
@@ -284,7 +299,7 @@ def remove_batch(st: ShardedTree, qb, ql,
                  faults: Optional[FaultPlan] = None,
                  retry: Optional[RetryPolicy] = None):
     """Routed tombstone removal. Returns ``(ShardedTree', report)``."""
-    qb, ql, owner = _owner_masks(st, qb, ql)
+    qb, ql, owner = _owner_masks(st, qb, ql, "remove")
 
     def run_one(t, mask, dev):
         t2, rep = B.remove_batch(t, _put(qb, dev), _put(ql, dev),
@@ -305,7 +320,7 @@ def insert_batch(st: ShardedTree, qb, ql, vals,
     their owner shard only (range partition preserved); a per-shard
     capacity overflow raises exactly as the unsharded op does —
     ``rebalance`` is the recovery for skew-driven overflow."""
-    qb, ql, owner = _owner_masks(st, qb, ql)
+    qb, ql, owner = _owner_masks(st, qb, ql, "insert")
     vals = jnp.asarray(vals)
     rounds_max = 0
 
@@ -386,7 +401,7 @@ def range_scan(st: ShardedTree, qb, ql, max_items: int = 64,
         raise ValueError(
             f"range_scan: max_items must be >= 1, got {max_items} — each "
             f"lane emits up to max_items (key, value) pairs")
-    qb, ql, owner = _owner_masks(st, qb, ql)
+    qb, ql, owner = _owner_masks(st, qb, ql, "range_scan")
     Bn = qb.shape[0]
     L = st.config.key_width
     stride = st.kid_stride
@@ -421,7 +436,7 @@ def range_scan(st: ShardedTree, qb, ql, max_items: int = 64,
             lambda: B.range_scan(t, _put(jnp.asarray(sqb), dev),
                                  _put(jnp.asarray(sql), dev),
                                  max_items=max_items, engine=engine),
-            faults, retry)
+            faults, retry, Bn)
         if res is None:
             failed |= active      # partial prefix, flagged — never silent
             obs.counter("shard.failed_lanes", op="range_scan").inc(

@@ -271,6 +271,45 @@ def test_shard_retry_and_degraded_events():
     assert obs.event_summary().get("shard.degraded") is None
 
 
+# ------------------------------------------ shard dispatch counters
+
+def _spread_batch(base):
+    """25 lanes over four shards of 30 keys: 10 to shard 0, 5 to shard 1,
+    none to shard 2, 10 to shard 3."""
+    picks = np.concatenate([base[0:10], base[30:35], base[90:100]])
+    return _keyset([int(x) for x in picks]), {0: 10, 1: 5, 2: 0, 3: 10}
+
+
+def test_shard_dispatch_counters_while_enabled():
+    """``shard.lanes`` counts what the router gave each shard and sums to
+    the batch per op; every launch computes the full batch, so
+    ``shard.launch_lanes`` is the batch times the shards launched."""
+    st, base = _sharded(n=120, n_shards=4)
+    q, per_shard = _spread_batch(base)
+    obs.enable()
+    SH.lookup_batch(st, q.bytes, q.lens)
+    SH.update_batch(st, q.bytes, q.lens, np.arange(25, dtype=np.int32))
+    for op in ("lookup", "update"):
+        lanes = {s: obs.get_metric("shard.lanes", op=op, shard=s).value
+                 for s in range(4)}
+        assert lanes == per_shard, op
+        assert sum(lanes.values()) == q.n
+        assert obs.get_metric("shard.launches", op=op).value == 3
+        assert obs.get_metric("shard.launch_lanes", op=op).value == 3 * q.n
+
+
+def test_shard_dispatch_counters_off_record_nothing():
+    st, base = _sharded(n=120, n_shards=4)
+    q, _ = _spread_batch(base)
+    v_off, rep_off = SH.lookup_batch(st, q.bytes, q.lens)
+    assert obs.all_metrics() == []
+    obs.enable()
+    v_on, rep_on = SH.lookup_batch(st, q.bytes, q.lens)
+    assert np.array_equal(v_off, v_on)
+    assert np.array_equal(rep_off.found, rep_on.found)
+    assert rep_off.found.all()
+
+
 # ------------------------------------- spans and scopes, telemetry off
 
 class _RecordedAnnotation:

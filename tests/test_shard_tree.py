@@ -459,3 +459,74 @@ def test_forced_multi_device_mesh():
                              os.path.abspath(__file__))), env=env)
     assert out.returncode == 0, out.stderr[-2000:]
     assert "OK" in out.stdout
+
+
+CONCURRENT_BUILD_SNIPPET = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import json
+import numpy as np
+import jax
+from repro import shard as S
+from repro.core import keys as K
+from repro.core.fbtree import TreeConfig, bulk_build, sharded_partition
+assert len(jax.devices()) == 4
+rng = np.random.default_rng(3)
+keys = sorted({int(x) for x in rng.integers(0, 2**62, size=1200)})[:1024]
+ks = K.make_keyset(keys, 8)
+vals = rng.integers(0, 2**31 - 1, size=len(keys), dtype=np.int32)
+cfg = TreeConfig.plan(max_keys=512, key_width=8)
+st = S.sharded_build(ks, vals, 4, cfg=cfg, device=True)
+out = {"identical": [], "devices": []}
+parts, _ = sharded_partition(ks, vals, 4)
+for t, (pks, pv), dev in zip(st.shards, parts, jax.devices()):
+    with jax.default_device(dev):
+        alone = bulk_build(cfg, pks, pv, device=True)
+    a, b = jax.tree_util.tree_leaves(t), jax.tree_util.tree_leaves(alone)
+    out["identical"].append(len(a) == len(b) and all(
+        np.asarray(x).dtype == np.asarray(y).dtype
+        and np.array_equal(np.asarray(x), np.asarray(y))
+        for x, y in zip(a, b)))
+    out["devices"].append(sorted({d.id for x in a for d in x.devices()}))
+small = TreeConfig.plan(max_keys=128, key_width=8)   # a shard holds 256
+try:
+    S.sharded_build(ks, vals, 4, cfg=small, device=True)
+    out["over_capacity"] = None
+except AssertionError as e:
+    out["over_capacity"] = str(e)
+print(json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def concurrent_build():
+    """One forced four-device process builds a four-shard tree with
+    ``sharded_build(device=True)`` (every shard's build launched before any
+    host sync), each shard's slice alone with ``bulk_build(device=True)``,
+    and an over-capacity sharded build."""
+    import json
+    import os
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env.pop("XLA_FLAGS", None)
+    env["PYTHONPATH"] = "src" + (
+        os.pathsep + env["PYTHONPATH"] if "PYTHONPATH" in env else "")
+    out = subprocess.run([sys.executable, "-c", CONCURRENT_BUILD_SNIPPET],
+                         capture_output=True, text=True, timeout=600,
+                         cwd=os.path.dirname(os.path.dirname(
+                             os.path.abspath(__file__))), env=env)
+    assert out.returncode == 0, out.stderr[-2000:]
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def test_concurrent_sharded_build_matches_per_shard_build(concurrent_build):
+    """Each shard of the concurrently launched build is bit-identical to
+    its slice built alone, and lives on its own device."""
+    assert concurrent_build["identical"] == [True] * 4
+    assert concurrent_build["devices"] == [[0], [1], [2], [3]]
+
+
+def test_concurrent_sharded_build_raises_capacity_error(concurrent_build):
+    """An over-capacity shard raises the same capacity error as a one-tree
+    ``bulk_build`` does, before any shard is launched."""
+    assert concurrent_build["over_capacity"] == "key_cap exceeded"
