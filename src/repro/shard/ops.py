@@ -1,12 +1,18 @@
 """Routed batch ops over a ShardedTree (DESIGN.md §7).
 
-Dispatch model: the router buckets the query batch by owning shard, then a
-host loop launches ONE jitted shard-local op per shard that owns work —
-the same ``core.batch_ops`` entry points every unsharded call site uses,
+Dispatch model: the router buckets the query batch by owning shard on the
+host's CPU device, where the router lives, over the host copy of the keys
+(no accelerator involved; a device batch is fetched first). A host loop
+then launches ONE jitted shard-local op per shard that owns work — the
+same ``core.batch_ops`` entry points every unsharded call site uses,
 through the same ``TraversalEngine`` (any backend/layout, including the
 fused kernels). Launches are asynchronous per device, so with a
-multi-device mesh the shards genuinely overlap; results are combined
-host-side by owner select.
+multi-device mesh the shards genuinely overlap. A
+lookup's results are copied device to device to shard 0's device and
+selected by owner there, in one small jitted program, and returned as
+device arrays whose host copies are already under way: for a host batch
+the call never waits on a device, so the caller's next work overlaps the
+shard lookups. Mutations and scans combine their reports on the host.
 
 Shapes stay static by running each shard over the *full* batch with the
 routed-op ``mask`` hook (``core.batch_ops``): masked-out lanes read
@@ -33,14 +39,17 @@ dropped or truncated), and :func:`rebalance` is the recovery barrier that
 re-admits the shard with fresh health and snapshots.
 
 Telemetry (DESIGN.md §9): the layer's three kinds of host work each run
-under a span, none inside another — ``shard.route`` (the router's launch
-and the owner fetch; in a scan, also each shard's parked lanes),
+under a span, none inside another — ``shard.route`` (the route on the
+CPU device, after a device batch's fetch; in a scan, also each shard's
+parked lanes),
 ``shard.dispatch`` (one shard's launch, retries included; the shard op's
-own ``op.<name>`` span nests in it) and ``shard.combine`` (per-shard
-results pulled to the host and merged by owner). While ``repro.obs`` is
-enabled, counters measure the full-width dispatch:
-``shard.lanes{op, shard}`` (lanes the router gave each shard, counted from
-the owners the route already fetched), ``shard.launches{op}`` and
+own ``op.<name>`` span nests in it) and ``shard.combine`` (a lookup: the
+results' device-to-device copies, the select's launch and the start of
+the host copies, with no wait; mutations and scans: per-shard results
+pulled to the host and merged by owner). While ``repro.obs`` is enabled,
+counters measure the full-width dispatch: ``shard.lanes{op, shard}``
+(lanes the router gave each shard, counted from the owners the route
+left on the host), ``shard.launches{op}`` and
 ``shard.launch_lanes{op}`` (lanes the launched shard programs compute:
 the batch width per launch). ``launch_lanes`` over the lanes served is
 the masked overcompute.
@@ -72,7 +81,10 @@ DEFAULT_RETRY = RetryPolicy()
 
 
 class ShardOpReport(NamedTuple):
-    """Cross-shard op outcome (host numpy — produced after the combine).
+    """Cross-shard op outcome. For lookups ``found`` is a device array on
+    shard 0's device, like the returned values (``np.asarray`` fetches
+    it); every other field, and every field of a mutation's report, is
+    exact host numpy.
 
     A shard ends a routed op in exactly one of three states, and the
     report keeps them apart (healthy skips must never read as
@@ -82,7 +94,8 @@ class ShardOpReport(NamedTuple):
     (owned lanes but was unreachable: its lanes appear in ``degraded``
     for lookups or ``failed`` for mutations/scans).
     """
-    found: np.ndarray       # bool [B] — owner shard's found
+    found: np.ndarray       # bool [B] — owner shard's found (lookups: a
+    #                         jax.Array on shard 0's device)
     conflicts: np.ndarray   # int32 — in-batch dedupe losers (global, once)
     splits: np.ndarray      # int32 — leaf splits summed over shards
     error: np.ndarray       # bool — any shard hit a capacity error
@@ -110,26 +123,30 @@ def _put(x, dev):
 
 
 def _owner_masks(st: ShardedTree, qb, ql, opname: str):
-    """Route once; per-shard owner masks as host bools (span
-    ``shard.route``: the router's launch and the owner fetch, a host
-    sync). Counts ``shard.lanes`` per shard while telemetry is on."""
+    """Route once (span ``shard.route``); returns the owners as host
+    numpy.
+
+    The jitted :func:`route` runs on the host's CPU device, where the
+    router lives, over host copies of the keys: a host batch launches
+    nothing on an accelerator and waits for none (a device batch's fetch
+    is the one sync). Counts ``shard.lanes`` per shard while telemetry is
+    on."""
     with obs.span("shard.route"):
-        qb = jnp.asarray(qb)
-        if qb.ndim != 2 or qb.shape[-1] != st.config.key_width:
-            got = "x".join(map(str, qb.shape))
+        hb, hl = np.asarray(qb), np.asarray(ql)
+        if hb.ndim != 2 or hb.shape[-1] != st.config.key_width:
+            got = "x".join(map(str, hb.shape))
             raise ValueError(
                 f"query batch shape [{got}] does not match the tree's key "
                 f"width {st.config.key_width}: routing compares packed "
                 f"words, so keys must be zero-padded to exactly key_width "
                 f"bytes — build them with repro.core.keys.make_keyset("
                 f"keys, max_key_len={st.config.key_width})")
-        ql = jnp.asarray(ql)
-        owner = np.asarray(route(st.router, qb, ql))
+        owner = np.asarray(route(st.router, hb, hl))
     if obs.enabled():
         per_shard = np.bincount(owner, minlength=st.n_shards)
         for s, n in enumerate(per_shard):
             obs.counter("shard.lanes", op=opname, shard=s).inc(int(n))
-    return qb, ql, owner
+    return owner
 
 
 def _dispatch(st: ShardedTree, s: int, opname: str, call,
@@ -175,26 +192,38 @@ def _dispatch(st: ShardedTree, s: int, opname: str, call,
     return None
 
 
+@jax.jit
+def _select_by_owner(slot, vals, founds):
+    """Lane ``i`` takes ``vals[slot[i]]`` / ``founds[slot[i]]``: the
+    combine of a routed lookup, one small program on shard 0's
+    device."""
+    v, f = vals[0], founds[0]
+    for k in range(1, len(vals)):
+        v = jnp.where(slot == k, vals[k], v)
+        f = jnp.where(slot == k, founds[k], f)
+    return v, f
+
+
 def lookup_batch(st: ShardedTree, qb, ql,
                  engine: Optional[TraversalEngine] = None,
                  faults: Optional[FaultPlan] = None,
                  retry: Optional[RetryPolicy] = None):
     """Batched point lookup across shards. Returns ``(vals [B], report)``;
-    ``vals``/``found`` are bit-identical to ``core.batch_ops.lookup_batch``
-    on one unsharded tree over the same keys.
+    ``vals`` and ``report.found`` are device arrays on shard 0's device,
+    their host copies already started, and bit-identical to
+    ``core.batch_ops.lookup_batch`` on one unsharded tree over the same
+    keys. For a host batch the call never waits on a device.
 
     Degradation: lanes owned by an unreachable shard are served from that
     shard's last-barrier snapshot (``report.degraded`` — correct as of the
     barrier, possibly stale) rather than failed; reads prefer staleness
     over unavailability. ``report.failed`` stays all-False for lookups.
     """
-    qb, ql, owner = _owner_masks(st, qb, ql, "lookup")
-    Bn = qb.shape[0]
-    vals = np.zeros((Bn,),
-                    dtype=jax.dtypes.canonicalize_dtype(st.config.val_dtype))
-    found = np.zeros((Bn,), dtype=bool)
+    owner = _owner_masks(st, qb, ql, "lookup")
+    Bn = owner.shape[0]
     degraded = np.zeros((Bn,), dtype=bool)
-    pending = []
+    slot = np.zeros((st.n_shards,), dtype=np.int32)  # shard -> its result
+    results = []
     hit = 0
     skipped = 0
     dropped = []
@@ -223,11 +252,21 @@ def lookup_batch(st: ShardedTree, qb, ql,
         else:
             v, rep = res
             hit += 1
-        pending.append((sel, v, rep.found))     # async: combine later
+        slot[s] = len(results)
+        results.append((v, rep.found))         # async: combine later
     with obs.span("shard.combine"):
-        for sel, v, f in pending:
-            vals[sel] = np.asarray(v)[sel]
-            found[sel] = np.asarray(f)[sel]
+        home = st.devices[0]                    # None: the default device
+        if results:
+            vals, found = _select_by_owner(
+                _put(slot[owner], home),
+                tuple(_put(v, home) for v, _ in results),
+                tuple(_put(f, home) for _, f in results))
+        else:                                   # an empty batch
+            vals = jnp.zeros((0,), jax.dtypes.canonicalize_dtype(
+                st.config.val_dtype), device=home)
+            found = jnp.zeros((0,), bool, device=home)
+        vals.copy_to_host_async()
+        found.copy_to_host_async()
     rep = ShardOpReport(found=found, conflicts=np.int32(0),
                         splits=np.int32(0), error=np.bool_(False),
                         owner=owner, shards_hit=hit,
@@ -281,7 +320,7 @@ def update_batch(st: ShardedTree, qb, ql, vals,
                  faults: Optional[FaultPlan] = None,
                  retry: Optional[RetryPolicy] = None):
     """Routed blind update. Returns ``(ShardedTree', report)``."""
-    qb, ql, owner = _owner_masks(st, qb, ql, "update")
+    owner = _owner_masks(st, qb, ql, "update")
     vals = jnp.asarray(vals)
 
     def run_one(t, mask, dev):
@@ -299,7 +338,7 @@ def remove_batch(st: ShardedTree, qb, ql,
                  faults: Optional[FaultPlan] = None,
                  retry: Optional[RetryPolicy] = None):
     """Routed tombstone removal. Returns ``(ShardedTree', report)``."""
-    qb, ql, owner = _owner_masks(st, qb, ql, "remove")
+    owner = _owner_masks(st, qb, ql, "remove")
 
     def run_one(t, mask, dev):
         t2, rep = B.remove_batch(t, _put(qb, dev), _put(ql, dev),
@@ -320,7 +359,7 @@ def insert_batch(st: ShardedTree, qb, ql, vals,
     their owner shard only (range partition preserved); a per-shard
     capacity overflow raises exactly as the unsharded op does —
     ``rebalance`` is the recovery for skew-driven overflow."""
-    qb, ql, owner = _owner_masks(st, qb, ql, "insert")
+    owner = _owner_masks(st, qb, ql, "insert")
     vals = jnp.asarray(vals)
     rounds_max = 0
 
@@ -401,8 +440,8 @@ def range_scan(st: ShardedTree, qb, ql, max_items: int = 64,
         raise ValueError(
             f"range_scan: max_items must be >= 1, got {max_items} — each "
             f"lane emits up to max_items (key, value) pairs")
-    qb, ql, owner = _owner_masks(st, qb, ql, "range_scan")
-    Bn = qb.shape[0]
+    owner = _owner_masks(st, qb, ql, "range_scan")
+    Bn = owner.shape[0]
     L = st.config.key_width
     stride = st.kid_stride
     vdt = jax.dtypes.canonicalize_dtype(st.config.val_dtype)

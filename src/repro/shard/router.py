@@ -8,15 +8,19 @@ replicated to every dispatch site. Shard ``s`` owns the key range
 an inner node absorbs keys below ``anchors[0]``).
 
 Routing uses the same packed-word compares the tree itself descends with
-(``core.keys.pack_words_j``): split keys are packed once into
-order-preserving int32 words at construction, and :func:`route` resolves a
-query batch with one ``[B, S, W]`` vectorized 3-way compare — first
-differing word decides, equal padded words fall back to the length
-tie-break, exactly ``core.keys.compare_padded``'s order at a quarter of
-the columns.
+(``core.keys.pack_words_j``): split keys are packed once into the same
+order-preserving int32 words at construction (by its numpy twin
+``pack_words``), and :func:`route` resolves a query batch with one
+``[B, S, W]`` vectorized 3-way compare — first differing word decides,
+equal padded words fall back to the length tie-break, exactly
+``core.keys.compare_padded``'s order at a quarter of the columns.
 
 ``ShardRouter`` is a NamedTuple of arrays (a pytree), so it rides through
-``jax.jit`` as a traced input; the shard count is its shape.
+``jax.jit`` as a traced input; the shard count is its shape. Its arrays
+live on the host's CPU device: routed ops route every batch there, on the
+host copy of the keys, so routing neither launches on nor waits for an
+accelerator. JAX's CPU backend must therefore be present (on a TPU host
+``JAX_PLATFORMS`` unset, or ``tpu,cpu``).
 """
 from __future__ import annotations
 
@@ -41,21 +45,32 @@ class ShardRouter(NamedTuple):
     """
     split_bytes: jnp.ndarray   # uint8 [S, L]
     split_lens: jnp.ndarray    # int32 [S]
-    split_words: jnp.ndarray   # int32 [S, W] — pack_words_j(split_bytes)
+    split_words: jnp.ndarray   # int32 [S, W] — pack_words(split_bytes)
 
     @property
     def n_shards(self) -> int:
         return int(self.split_bytes.shape[0])
 
 
+def _host_device():
+    try:
+        return jax.devices("cpu")[0]
+    except RuntimeError as e:
+        raise RuntimeError(
+            "the shard router lives on the host's CPU device, and JAX has "
+            "no CPU backend here: leave JAX_PLATFORMS unset or list cpu in "
+            "it (e.g. JAX_PLATFORMS=tpu,cpu)") from e
+
+
 def make_router(split_keys) -> ShardRouter:
     """Build a router from ``fbtree.sharded_partition``'s ``split_keys``
-    (a sequence of ``(bytes_row, len)`` per shard, ascending)."""
+    (a sequence of ``(bytes_row, len)`` per shard, ascending), on the
+    host's CPU device."""
     sb = np.stack([np.asarray(b, dtype=np.uint8) for b, _ in split_keys])
     sl = np.asarray([int(l) for _, l in split_keys], dtype=np.int32)
-    return ShardRouter(split_bytes=jnp.asarray(sb),
-                       split_lens=jnp.asarray(sl),
-                       split_words=jnp.asarray(K.pack_words_j(sb)))
+    return jax.device_put(
+        ShardRouter(split_bytes=sb, split_lens=sl,
+                    split_words=K.pack_words(sb)), _host_device())
 
 
 @jax.jit

@@ -89,7 +89,7 @@ class ShardedTree:
 
     Not a jax pytree — dispatch is a host loop launching one jitted op per
     shard (async on that shard's device); only the per-shard FBTrees and
-    the router live on device.
+    the router live on device (the router on the host's CPU device).
 
     ``health`` is deliberately a *shared mutable* object: routed ops
     return a functionally-updated ShardedTree (``replace``), and a shard

@@ -310,6 +310,31 @@ def test_shard_dispatch_counters_off_record_nothing():
     assert rep_off.found.all()
 
 
+def test_shard_lanes_count_device_batches_like_host_ones():
+    """A device batch is routed on the host too, from its fetched copy:
+    ``shard.lanes`` counts what the router gave each shard exactly as for
+    the same keys sent from the host, per op. Nothing while telemetry is
+    off."""
+    import jax.numpy as jnp
+    st, base = _sharded(n=120, n_shards=4)
+    q, per_shard = _spread_batch(base)
+    dq = (jnp.asarray(q.bytes), jnp.asarray(q.lens))
+    vals = np.arange(25, dtype=np.int32)
+
+    def ops():
+        SH.lookup_batch(st, q.bytes, q.lens)
+        SH.lookup_batch(st, *dq)
+        SH.update_batch(st, *dq, vals)
+    ops()
+    assert obs.all_metrics() == []
+    obs.enable()
+    ops()
+    for op, n in (("lookup", 2), ("update", 1)):
+        lanes = {s: obs.get_metric("shard.lanes", op=op, shard=s).value
+                 for s in range(4)}
+        assert lanes == {s: n * k for s, k in per_shard.items()}, op
+
+
 # ------------------------------------- spans and scopes, telemetry off
 
 class _RecordedAnnotation:

@@ -11,6 +11,7 @@ the whole matrix reuses one jit specialization per op.
 import subprocess
 import sys
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -178,6 +179,81 @@ def test_router_boundaries():
     assert owner.tolist() == want, owner.tolist()
 
 
+def _route_reference(splits, lens, qb, ql):
+    """Plain owner per query: the last split key (bytes, then length) at
+    or below it, 0 below every split key."""
+    keys = [(bytes(b), int(n)) for b, n in zip(splits, lens)]
+    out = []
+    for b, n in zip(qb, ql):
+        q = (bytes(b), int(n))
+        out.append(max([s for s, k in enumerate(keys) if q >= k],
+                       default=0))
+    return np.asarray(out, np.int32)
+
+
+def _route_queries(case, st, rng):
+    """A query batch for :func:`test_host_route_matches_router`."""
+    W = st.config.key_width
+    if case == "edges":
+        sb = np.asarray(st.router.split_bytes)
+        sl = np.asarray(st.router.split_lens)
+        rows, lens = [], []
+        for b, n in zip(sb, sl):
+            for m in (n - 1, n, n + 1):      # the length tie-break decides
+                if 1 <= m <= W:
+                    r = b.copy()
+                    r[m:] = 0
+                    rows.append(r)
+                    lens.append(m)
+        for r, m in ((np.zeros(W, np.uint8), 1),            # below split[0]
+                     (np.r_[1, 2, np.zeros(W - 2)], 2),
+                     (np.full(W, 0xFF), W),                 # all-0xFF
+                     (np.r_[np.full(3, 0xFF), np.zeros(W - 3)], 3)):
+            rows.append(np.asarray(r, np.uint8))
+            lens.append(m)
+        return np.stack(rows), np.asarray(lens, np.int32)
+    ql = rng.integers(1, W + 1, size=256).astype(np.int32)
+    qb = rng.integers(0, 256, size=(256, W)).astype(np.uint8)
+    qb[np.arange(W)[None, :] >= ql[:, None]] = 0
+    return qb, ql
+
+
+@pytest.mark.parametrize("n_shards", SHARD_COUNTS)
+@pytest.mark.parametrize("case", ("random", "edges", "device_batch"))
+def test_host_route_matches_router(monkeypatch, case, n_shards):
+    """Every batch, a device batch too, is routed once on the host's CPU
+    device, where the router lives, over host copies of the keys; the
+    owners are bit-identical to ``route`` and to a plain byte compare —
+    below the first split key, on a split key, across the length
+    tie-break (keys that differ only in trailing zero bytes), all-0xFF."""
+    keys = [bytes([8 + 6 * i, 7]) + b"\0" * z
+            for i in range(40) for z in range(3)]
+    ks = K.make_keyset(keys, 8)
+    cfg = TreeConfig.plan(max_keys=512, key_width=8)
+    st = S.sharded_build(ks, np.arange(ks.n, dtype=np.int32), n_shards,
+                         cfg=cfg)
+    qb, ql = _route_queries(case, st, np.random.default_rng(n_shards))
+    routed = []
+
+    def spy(router, b, l):
+        routed.append(all(isinstance(x, np.ndarray) for x in (b, l)))
+        return S.route(router, b, l)
+    monkeypatch.setattr(S.ops, "route", spy)
+    assert st.router.split_words.devices() == {jax.devices("cpu")[0]}
+    args = ((jnp.asarray(qb), jnp.asarray(ql)) if case == "device_batch"
+            else (qb, ql))
+    owner = S.ops._owner_masks(st, *args, "lookup")
+    assert routed == [True]                     # once, over host keys
+    assert isinstance(owner, np.ndarray) and owner.dtype == np.int32
+    want = np.asarray(S.route(st.router, jnp.asarray(qb), jnp.asarray(ql)))
+    assert np.array_equal(owner, want)
+    assert np.array_equal(owner, _route_reference(
+        np.asarray(st.router.split_bytes), np.asarray(st.router.split_lens),
+        qb, ql))
+    if case == "edges" and n_shards > 1:
+        assert set(owner.tolist()) == set(range(n_shards))
+
+
 def test_sharded_partition_invariants():
     """Balanced contiguous runs, ascending split keys, sizes differ by at
     most one, and the runs concatenate back to the sorted key set."""
@@ -237,9 +313,10 @@ def test_routed_mask_hook():
 
 
 def test_host_results_keep_device_value_dtype():
-    """The host arrays a routed lookup and scan combine into have the
-    dtype the shards' ``leaf_val`` holds: a 64-bit ``val_dtype`` with
-    64-bit JAX off gives 32-bit results, as the device arrays do."""
+    """The arrays a routed lookup and scan combine into have the dtype
+    the shards' ``leaf_val`` holds, an empty batch's too: a 64-bit
+    ``val_dtype`` with 64-bit JAX off gives 32-bit results, as the device
+    arrays do."""
     keys = list(range(0, 600, 3))
     ks = K.make_keyset(keys, 8)
     vals = np.arange(len(keys), dtype=np.int32)
@@ -249,6 +326,8 @@ def test_host_results_keep_device_value_dtype():
     v, rep = S.lookup_batch(st, ks.bytes, ks.lens)
     assert rep.found.all() and v.dtype == want
     assert (v == vals).all()
+    v, rep = S.lookup_batch(st, ks.bytes[:0], ks.lens[:0])   # no lanes
+    assert v.shape == rep.found.shape == (0,) and v.dtype == want
     _, sv, em, _, _ = S.range_scan(st, ks.bytes[:2], ks.lens[:2], max_items=4)
     assert sv.dtype == want and int(em[0]) == 4
 
@@ -433,9 +512,34 @@ st = S.sharded_build(ks, vals, 4, cfg=cfg, device=True)
 devs = {list(t.arrays.key_count.devices())[0] for t in st.shards}
 assert len(devs) == 4, devs
 ref = bulk_build(cfg, ks, vals)
-v_ref, _ = B.lookup_batch(ref, ks.bytes, ks.lens)
-v_sh, rep = S.lookup_batch(st, ks.bytes, ks.lens)
-assert rep.found.all() and (np.asarray(v_ref) == v_sh).all()
+v_ref, rep_ref = B.lookup_batch(ref, ks.bytes, ks.lens)
+f_ref = np.asarray(rep_ref.found)
+want_owner = np.asarray(S.route(st.router, jax.numpy.asarray(ks.bytes),
+                                jax.numpy.asarray(ks.lens)))
+route = S.ops.route
+
+
+def host_route_only(router, qb, ql):
+    # routed on the host's CPU device over host keys: nothing launched on
+    # a shard's device and waited for
+    assert router.split_words.devices() == {jax.devices("cpu")[0]}
+    assert isinstance(qb, np.ndarray) and isinstance(ql, np.ndarray)
+    return route(router, qb, ql)
+
+
+S.ops.route = host_route_only
+home = st.devices[0]
+for q in (np.arange(ks.n), np.flatnonzero(want_owner % 2 == 0)):
+    v_sh, rep = S.lookup_batch(st, ks.bytes[q], ks.lens[q])
+    for x in (v_sh, rep.found):                # futures on shard 0's device
+        assert isinstance(x, jax.Array) and x.devices() == {home}
+    assert np.array_equal(np.asarray(v_sh), np.asarray(v_ref)[q])
+    assert np.array_equal(np.asarray(rep.found), f_ref[q]) and f_ref.all()
+    assert np.array_equal(rep.owner, want_owner[q])
+    hit = len(set(want_owner[q].tolist()))
+    assert (rep.shards_hit, rep.shards_skipped) == (hit, 4 - hit)
+assert hit == 2
+S.ops.route = route
 gk, v, em, _, _ = S.range_scan(st, ks.bytes[:4], ks.lens[:4], max_items=64)
 kr, vr, er, _ = B.range_scan(ref, ks.bytes[:4], ks.lens[:4], max_items=64)
 assert (np.asarray(er) == em).all() and (np.asarray(vr) == v).all()
@@ -446,7 +550,10 @@ print("OK")
 def test_forced_multi_device_mesh():
     """End-to-end on a real 4-device mesh (forced CPU devices — the env
     must be set before jax imports, hence the subprocess): one shard per
-    device, ops parity across devices."""
+    device, ops parity across devices. A host batch's lookup routes on
+    the host's CPU device and returns device arrays on shard 0's device,
+    bit-identical once fetched, with the owners and the hit and skip
+    counts of the route."""
     import os
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"     # the parent may hold the accelerator
