@@ -69,22 +69,22 @@ def make_dataset(name: str, n: int, seed: int = 7) -> Tuple[List, int]:
 DATASETS = ("rand-int", "3-gram", "ycsb", "twitter", "url")
 
 
-def build_tree(keys, width, fs: int = 4, ns: int = 64,
-               stacked: bool = False) -> Tuple[FBTree, K.KeySet]:
+def build_tree(keys, width, fs: int = 4,
+               ns: int = 64) -> Tuple[FBTree, K.KeySet]:
     ks = K.make_keyset(keys, width)
     cfg = TreeConfig.plan(max_keys=int(len(keys) * 2.5), key_width=width,
-                          fs=fs, ns=ns, stacked=stacked)
+                          fs=fs, ns=ns)
     vals = np.arange(len(keys), dtype=np.int32)
     return bulk_build(cfg, ks, vals), ks
 
 
-def make_engine(backend: str = "jnp", layout: str = None) -> TraversalEngine:
+def make_engine(backend: str = "jnp") -> TraversalEngine:
     """CLI-facing engine selector: turns constructor validation errors into
     a clean SystemExit before a long benchmark run starts."""
     try:
-        return TraversalEngine(backend=backend, layout=layout)
+        return TraversalEngine(backend=backend)
     except ValueError as e:
-        raise SystemExit(f"bad --backend/--layout: {e}")
+        raise SystemExit(f"bad --backend: {e}")
 
 
 def zipf_indices(rng, n_keys: int, n_ops: int, theta: float) -> np.ndarray:

@@ -22,8 +22,8 @@ STEPS = ("base", "+prefix", "+feature2", "+feature4", "+hashtag")
 
 
 def run(datasets=("3-gram", "ycsb", "twitter", "url"), n_keys=20_000,
-        n_ops=16_384, seed=13, backend="jnp", layout=None) -> List[Dict]:
-    engine = make_engine(backend, layout)
+        n_ops=16_384, seed=13, backend="jnp") -> List[Dict]:
+    engine = make_engine(backend)
     rows = []
     rng = np.random.default_rng(seed)
     for ds in datasets:
@@ -33,8 +33,7 @@ def run(datasets=("3-gram", "ycsb", "twitter", "url"), n_keys=20_000,
         qb, ql = jnp.asarray(ks.bytes[idx]), jnp.asarray(ks.lens[idx])
         trees = {}
         for fs in (2, 4):
-            cfg = TreeConfig.plan(max_keys=2 * n_keys, key_width=width, fs=fs,
-                                  stacked=(layout == "stacked"))
+            cfg = TreeConfig.plan(max_keys=2 * n_keys, key_width=width, fs=fs)
             trees[fs] = bulk_build(cfg, ks, np.arange(n_keys, dtype=np.int32))
         plan = [("base", trees[4], "base"),
                 ("+prefix", trees[4], "prefix"),

@@ -1,16 +1,16 @@
 """Benchmark driver: one module per paper table/figure.
 
   PYTHONPATH=src python -m benchmarks.run [--fast] [--only ycsb,...]
-      [--backend {jnp,pallas,...}] [--layout {tuple,stacked}] [--smoke]
+      [--backend {jnp,pallas,...}] [--smoke]
 
-``--suite`` is an alias for ``--only``. ``--backend``/``--layout`` apply
-to the engine-selecting suites (ycsb, factor); the traverse suite always
-A/Bs every backend×layout×stats combination and the scan suite A/Bs both
-scan backends (jnp reference vs the fused scan kernel) on ordered and
-dirtied leaves. ``--smoke`` is the CI guard: tiny trees, one timing pass,
-all traversal backends (incl. the fused descent and fused scan kernels in
-interpret mode) parity-checked — and ``BENCH_traverse.json`` is left
-untouched so CI runs never overwrite the perf trajectory anchor.
+``--suite`` is an alias for ``--only``. ``--backend`` applies to the
+engine-selecting suites (ycsb, factor); the traverse suite always A/Bs
+every backend×stats combination and the scan suite A/Bs the jnp chain
+walk against its always-sort baseline on ordered and dirtied leaves.
+``--smoke`` is the CI guard: tiny trees, one timing pass, all traversal
+backends (the Pallas one in interpret mode) parity-checked — and
+``BENCH_traverse.json`` is left untouched so CI runs never overwrite the
+perf trajectory anchor.
 
 The ``traverse`` suite writes ``BENCH_traverse.json`` at the repo root;
 the ``build`` suite (host vs device ``bulk_build`` + ``rebuild``) and the
@@ -44,7 +44,7 @@ SUITES = {
                    n_keys=8_000 if fast else 20_000,
                    n_ops=8_192 if fast else 16_384, **eng),
                factor_analysis.COLUMNS),
-    "traverse": ("Engine A/B — traversal backends × layouts × stats",
+    "traverse": ("Engine A/B — traversal backends × stats",
                  lambda fast, **kw: traverse_bench.run(
                      n_keys=8_000 if fast else 20_000,
                      n_ops=8_192 if fast else 16_384, **kw),
@@ -74,7 +74,7 @@ SUITES = {
                  lambda fast: hardware_counters.run(
                      n_keys=10_000 if fast else 50_000),
                  hardware_counters.COLUMNS),
-    "scan": ("Fig.17(E) — range scan engine A/B (jnp vs fused × "
+    "scan": ("Fig.17(E) — range scan A/B (lazy vs always-sort × "
              "ordered/dirty)",
              lambda fast, **kw: scan.run(n_keys=8_000 if fast else 20_000,
                                          **kw),
@@ -106,9 +106,6 @@ def main(argv=None):
     ap.add_argument("--out", default="out/bench")
     ap.add_argument("--backend", default="jnp",
                     help="traversal branch backend (jnp, pallas, ...)")
-    ap.add_argument("--layout", default=None, choices=(None, "tuple",
-                                                       "stacked"),
-                    help="descent layout (default: tree config)")
     args = ap.parse_args(argv)
     use_compile_cache(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
@@ -119,7 +116,7 @@ def main(argv=None):
     report = []     # per-suite timing/status -> out/bench_report.json
     for name in names:
         title, fn, cols = SUITES[name]
-        eng = (dict(backend=args.backend, layout=args.layout)
+        eng = (dict(backend=args.backend)
                if name in _ENGINE_SUITES else {})
         if args.smoke and name in ("traverse", "scan", "shard"):
             eng["smoke"] = True

@@ -1,14 +1,10 @@
-"""Range-scan benchmark (YCSB-E side of paper Fig. 17): scan engine A/B.
+"""Range-scan benchmark (YCSB-E side of paper Fig. 17).
 
-Per dataset, times the scan engine's two backends (DESIGN.md §6) on the
-same trees and query streams:
+Per dataset, times the jnp chain walk (DESIGN.md §6: engine descent +
+early-exit ``while_loop`` + lazy-rearrangement cond) on the same trees and
+query streams.
 
-* ``jnp``   — the chain-walk reference (engine descent + early-exit
-  ``while_loop`` + lazy-rearrangement cond);
-* ``fused`` — the whole-scan Pallas kernel (``kernels/fused_scan``,
-  interpret mode off-TPU).
-
-Each backend is measured on an all-ordered tree (``scan_Mitems`` — the
+It is measured on an all-ordered tree (``scan_Mitems`` — the
 lazy-rearrangement fast path, no per-hop sorting) and on a tree whose
 leaves were dirtied by in-place inserts (``dirty_Mitems`` — the sort cond
 fires). ``alwayssort_Mitems`` is the pre-scan-engine baseline (the old
@@ -17,9 +13,10 @@ reproduces it bit-identically), so ``speedup_vs_alwayssort`` is the win the
 ordered fast path carries into the anchor. The trie-pointer-chasing model
 (each successor found by a fresh root descent) stays for paper context.
 
-Every row cross-checks both backends and the always-sort baseline for
-bit-identical emissions before timing — a scan-kernel regression fails the
-suite (and CI, via ``--smoke``) rather than reporting wrong throughput.
+Every row cross-checks the stats-on and stats-free scans and the
+always-sort baseline for bit-identical emissions before timing — a scan
+regression fails the suite (and CI, via ``--smoke``) rather than reporting
+wrong throughput.
 Rows land in ``BENCH_traverse.json`` under ``scan_rows``.
 """
 from __future__ import annotations
@@ -35,9 +32,6 @@ from repro.core import keys as K
 from repro.core.traverse import TraversalEngine
 
 from .common import build_tree, make_dataset, timed
-
-BACKENDS = ("jnp", "fused")
-
 
 def _dirty_tree(tree, ks, rng, n_extra):
     """In-place-insert siblings of existing keys so leaves ACROSS the
@@ -81,7 +75,7 @@ def run(datasets=("rand-int", "ycsb", "url"), n_keys=20_000, n_scans=512,
         idx = rng.integers(0, n_keys, size=n_scans)
         qb, ql = jnp.asarray(ks.bytes[idx]), jnp.asarray(ks.lens[idx])
 
-        # ---- parity gate: both backends + the always-sort baseline emit
+        # ---- parity gate: the scan and the always-sort baseline emit
         # bit-identical pairs on the ordered AND the dirtied tree
         ref = {}
         # ONE compiled always-sort baseline serves both the parity gate and
@@ -109,46 +103,40 @@ def run(datasets=("rand-int", "ycsb", "url"), n_keys=20_000, n_scans=512,
         t_chase = timed(chase_fn, warmup=1, iters=1 if smoke else 3) \
             * (scan_len / 4)
 
-        for backend in BACKENDS:
-            # throughput runs stats-free (the serving configuration);
-            # parity was pinned above with stats on
-            eng = TraversalEngine(backend=backend,
-                                  layout="stacked" if backend == "fused"
-                                  else None,
-                                  collect_stats=False)
-            for label, t in (("ordered", tree), ("dirty", t_dirty)):
-                got = B.range_scan(t, qb, ql, max_items=scan_len, engine=eng)
-                for a, b, nm in zip(ref[label][:3], got[:3],
-                                    ("kid", "val", "emitted")):
-                    assert (a == np.asarray(b)).all(), \
-                        f"{ds}/{label}: {backend} diverges on {nm}"
+        # throughput runs stats-free (the serving configuration); parity
+        # was pinned above with stats on
+        eng = TraversalEngine("jnp", collect_stats=False)
+        for label, t in (("ordered", tree), ("dirty", t_dirty)):
+            got = B.range_scan(t, qb, ql, max_items=scan_len, engine=eng)
+            for a, b, nm in zip(ref[label][:3], got[:3],
+                                ("kid", "val", "emitted")):
+                assert (a == np.asarray(b)).all(), \
+                    f"{ds}/{label}: stats-free scan diverges on {nm}"
 
-            def scan_fn(t):
-                return B.range_scan(t, qb, ql, max_items=scan_len,
-                                    engine=eng)[1]
-            t_ord = timed(lambda: scan_fn(tree), warmup=1,
-                          iters=1 if smoke else 5)
-            t_dirt = timed(lambda: scan_fn(t_dirty), warmup=1,
-                           iters=1 if smoke else 5)
-            row = {
-                "dataset": ds, "n_keys": n_keys, "n_scans": n_scans,
-                "scan_len": scan_len, "backend": backend,
-                "scan_Mitems": round(n_scans * scan_len / t_ord / 1e6, 3),
-                "dirty_Mitems": round(n_scans * scan_len / t_dirt / 1e6, 3),
-                "chase_model_Mitems": round(
-                    n_scans * scan_len / t_chase / 1e6, 3),
-                "parity": "ok",
-            }
-            if backend == "jnp":
-                # the pre-engine baseline: every visited leaf re-sorted on
-                # every hop (bit-identical outputs, checked above; reuses
-                # the parity gate's compiled slow_ref)
-                t_slow = timed(lambda: slow_ref(tree)[1], warmup=1,
-                               iters=1 if smoke else 5)
-                row["alwayssort_Mitems"] = round(
-                    n_scans * scan_len / t_slow / 1e6, 3)
-                row["speedup_vs_alwayssort"] = round(t_slow / t_ord, 2)
-            rows.append(row)
+        def scan_fn(t):
+            return B.range_scan(t, qb, ql, max_items=scan_len,
+                                engine=eng)[1]
+        t_ord = timed(lambda: scan_fn(tree), warmup=1,
+                      iters=1 if smoke else 5)
+        t_dirt = timed(lambda: scan_fn(t_dirty), warmup=1,
+                       iters=1 if smoke else 5)
+        # the pre-engine baseline: every visited leaf re-sorted on every
+        # hop (bit-identical outputs, checked above; reuses the parity
+        # gate's compiled slow_ref)
+        t_slow = timed(lambda: slow_ref(tree)[1], warmup=1,
+                       iters=1 if smoke else 5)
+        rows.append({
+            "dataset": ds, "n_keys": n_keys, "n_scans": n_scans,
+            "scan_len": scan_len, "backend": "jnp",
+            "scan_Mitems": round(n_scans * scan_len / t_ord / 1e6, 3),
+            "dirty_Mitems": round(n_scans * scan_len / t_dirt / 1e6, 3),
+            "alwayssort_Mitems": round(
+                n_scans * scan_len / t_slow / 1e6, 3),
+            "speedup_vs_alwayssort": round(t_slow / t_ord, 2),
+            "chase_model_Mitems": round(
+                n_scans * scan_len / t_chase / 1e6, 3),
+            "parity": "ok",
+        })
     return rows
 
 

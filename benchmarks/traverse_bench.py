@@ -1,6 +1,5 @@
-"""Traversal engine A/B: backends (jnp vs pallas-interpret vs the fused
-whole-descent kernel) × layouts (tuple vs stacked) × stats (on vs the
-stats-free hot path) on identical trees and query streams — plus the build
+"""Traversal engine A/B: backends (jnp vs pallas-interpret) × stats (on vs
+the stats-free hot path) on identical trees and query streams — plus the build
 benchmark (:func:`run_build`): host-numpy vs device-jnp ``bulk_build``
 across datasets and tree sizes, with a bit-exact parity cross-check
 (DESIGN.md §5).
@@ -18,8 +17,8 @@ the repo root (``rows`` = traversal A/B, ``build_rows`` = host-vs-device
 build) so the perf trajectory of future kernel PRs starts here.
 
 ``smoke=True`` is the CI mode (`benchmarks/run.py --suite traverse
---smoke`): tiny trees, one timing iteration, every backend including
-``fused`` in interpret mode — the parity asserts are the point; a
+--smoke`): tiny trees, one timing iteration, every backend, ``pallas`` in
+interpret mode — the parity asserts are the point; a
 kernel-path regression fails CI instead of rotting until the next bench
 run.
 """
@@ -40,9 +39,7 @@ from repro.core.traverse import TraversalEngine
 
 from .common import build_tree, make_dataset, timed, zipf_indices
 
-COMBOS = [("jnp", "tuple"), ("jnp", "stacked"),
-          ("pallas", "tuple"), ("pallas", "stacked"),
-          ("fused", "stacked")]
+BACKENDS = ("jnp", "pallas")
 
 
 def run(datasets=("ycsb", "url"), n_keys=20_000, n_ops=16_384,
@@ -60,9 +57,9 @@ def run(datasets=("ycsb", "url"), n_keys=20_000, n_ops=16_384,
         qb, ql = jnp.asarray(ks.bytes[idx]), jnp.asarray(ks.lens[idx])
         ref = None
         ref_found = None
-        for backend, layout in COMBOS:
+        for backend in BACKENDS:
             for stats_on in (True, False):
-                eng = TraversalEngine(backend=backend, layout=layout,
+                eng = TraversalEngine(backend=backend,
                                       collect_stats=stats_on)
                 def fn():
                     outs = []
@@ -88,15 +85,15 @@ def run(datasets=("ycsb", "url"), n_keys=20_000, n_ops=16_384,
                                             ("found", "key_compares",
                                              "suffix_bs", "feat_rounds")):
                             assert (a == b).all(), \
-                                f"{ds}: {backend}/{layout} diverges on {nm}"
+                                f"{ds}: {backend} diverges on {nm}"
                 else:
                     # stats-free contract: counters are zero by design,
                     # found-ness must still match the stats-on reference
                     assert (np.asarray(rep.found) == ref_found).all(), \
-                        f"{ds}: {backend}/{layout} stats-off diverges"
+                        f"{ds}: {backend} stats-off diverges"
                 row = {
                     "dataset": ds, "n_keys": len(keys), "n_ops": n_ops,
-                    "backend": backend, "layout": layout,
+                    "backend": backend,
                     "stats": "on" if stats_on else "off",
                     "Mops": round(n_ops / t / 1e6, 3),
                     "parity": "ok",
@@ -113,7 +110,7 @@ def run(datasets=("ycsb", "url"), n_keys=20_000, n_ops=16_384,
 
 # n_keys/n_ops ride along so the trajectory anchor stays comparable across
 # PRs — counters like key_cmp/op shift with tree size, not just with code
-COLUMNS = ["dataset", "n_keys", "n_ops", "backend", "layout", "stats",
+COLUMNS = ["dataset", "n_keys", "n_ops", "backend", "stats",
            "Mops", "key_cmp/op", "suffix_bs/op", "feat_rounds/op", "parity"]
 
 

@@ -26,13 +26,13 @@ SKEW = 0.99
 
 
 def run(datasets=DATASETS, n_keys=N_KEYS, n_ops=N_OPS, seed=11,
-        backend="jnp", layout=None) -> List[Dict]:
-    engine = make_engine(backend, layout)
+        backend="jnp") -> List[Dict]:
+    engine = make_engine(backend)
     rows = []
     rng = np.random.default_rng(seed)
     for ds in datasets:
         keys, width = make_dataset(ds, n_keys)
-        tree, ks = build_tree(keys, width, stacked=(layout == "stacked"))
+        tree, ks = build_tree(keys, width)
         idx = zipf_indices(rng, len(keys), n_ops, SKEW)
         qb = jnp.asarray(ks.bytes[idx])
         ql = jnp.asarray(ks.lens[idx])

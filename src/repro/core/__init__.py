@@ -1,5 +1,5 @@
 """FB+-tree core: the paper's data structure + batched latch-free ops in JAX."""
-from .fbtree import FBTree, TreeConfig, bulk_build, stack_levels
+from .fbtree import FBTree, TreeConfig, bulk_build
 from .keys import KeySet, make_keyset, encode_uint64, encode_int64
 from .branch import traverse, branch_level, BranchStats
 from .leaf import probe
@@ -15,7 +15,7 @@ from .faults import (FaultInjected, ShardDropped, FaultSpec, FaultPlan,
 from .lifecycle import TreeVersionManager, PublishReport
 
 __all__ = [
-    "FBTree", "TreeConfig", "bulk_build", "stack_levels", "KeySet",
+    "FBTree", "TreeConfig", "bulk_build", "KeySet",
     "make_keyset", "encode_uint64", "encode_int64", "traverse", "branch_level",
     "BranchStats", "probe", "TraversalEngine", "DEFAULT_ENGINE",
     "register_backend", "available_backends", "lookup_batch", "update_batch",

@@ -146,14 +146,14 @@ def lookup_variant(tree: FBTree, qb, ql, variant: str = "feature+hash",
     All variants descend through the traversal engine: the binary-search
     baselines are the registered ``binary`` / ``binary+prefix`` backends,
     and the feature variants use ``engine``'s backend (``jnp`` or
-    ``pallas``). ``engine`` also selects the descent layout.
+    ``pallas``).
     """
     assert variant in VARIANTS, variant
     eng = resolve_engine(engine)
     if variant in ("base", "prefix"):
         eng = TraversalEngine(
             backend="binary" if variant == "base" else "binary+prefix",
-            layout=eng.layout, collect_stats=eng.collect_stats)
+            collect_stats=eng.collect_stats)
     node_ids, _, stats = eng.traverse(tree, qb, ql, sibling_check=True)
     if variant == "feature+hash":
         found, slot, val, ls = probe(tree, node_ids, qb, ql,

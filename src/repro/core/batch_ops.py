@@ -30,7 +30,7 @@ from repro import obs
 from .branch import BranchStats
 from .fbtree import (BIG, EMPTY, FBTree, Level, TreeArrays, _device_build_jit,
                      chunk_of_pos, chunk_start, recompute_inner_meta,
-                     sort_pool, stack_levels)
+                     sort_pool)
 from .keys import compare_padded, fnv1a_tags, pack_words_j
 from .leaf import leaf_counts, leaf_rows, leaf_set, probe
 from .traverse import TraversalEngine, resolve_engine
@@ -81,7 +81,7 @@ def traverse_path(tree: FBTree, qb, ql, sibling_check: bool = True,
                   engine: Optional[TraversalEngine] = None):
     """Root-to-leaf traversal recording the node id at every level.
 
-    Delegates to the traversal engine (backend + layout selection); kept as
+    Delegates to the traversal engine (backend selection); kept as
     the stable call-site API for ops and benchmarks. Jitted (engine is
     static), so benchmarks can time the bare descent without probe work.
     """
@@ -94,20 +94,14 @@ def _traverse_probe(tree: FBTree, qb, ql, engine, sibling_check=True):
     descent, one hashtag leaf probe. Returns
     (leaf_ids, path, found, slot, val, branch_stats, leaf_stats).
 
-    Descent backends exposing a fused traverse+probe entry (DESIGN.md §3,
-    e.g. ``"fused"``) collapse the whole pipeline into one kernel launch;
-    level backends run the engine descent followed by the probe. Stats may
-    be ``None`` under a stats-free engine — ``_report`` zero-fills.
+    Stats may be ``None`` under a stats-free engine — ``_report``
+    zero-fills.
 
     The probe's ops carry the named scope ``leaf_probe`` (the descent's
     carry ``descent``, see ``TraversalEngine.traverse``): metadata only,
     so a device trace can split the program's time between the two.
     """
     eng = resolve_engine(engine)
-    fused = eng.probe_path()
-    if fused is not None:
-        return fused(tree, qb, ql, sibling_check=sibling_check,
-                     collect_stats=eng.collect_stats)
     leaf_ids, path, bstats = eng.traverse(
         tree, qb, ql, sibling_check=sibling_check)
     with jax.named_scope("leaf_probe"):
@@ -499,10 +493,7 @@ def _make_insert_round(cfg, max_ov: int, ins_cap: int,
                 tup_repop, parent_path)
             new_levels[lvl] = lvl2
             err = err | e
-        # keep both descent layouts coherent: splits rewrote inner nodes,
-        # so re-derive the stacked copy in-graph (pad + stack, shape-static)
-        arrays = arrays._replace(levels=tuple(new_levels),
-                                 stacked=stack_levels(tuple(new_levels)))
+        arrays = arrays._replace(levels=tuple(new_levels))
 
         done_orig = jnp.zeros((B,), bool).at[perm].set(done_sorted)
         new_pending = pending & ~done_orig
@@ -826,12 +817,7 @@ def _range_scan_jnp(tree: FBTree, qb, ql, max_items: int,
 @functools.partial(jax.jit, static_argnames=("max_items", "engine"))
 def _range_scan_jit(tree: FBTree, qb, ql, max_items: int = 64,
                     engine: Optional[TraversalEngine] = None):
-    eng = resolve_engine(engine)
-    fused = eng.scan_path()
-    if fused is not None:
-        return fused(tree, qb, ql, max_items=max_items,
-                     collect_stats=eng.collect_stats)
-    return _range_scan_jnp(tree, qb, ql, max_items, eng)
+    return _range_scan_jnp(tree, qb, ql, max_items, resolve_engine(engine))
 
 
 def range_scan(tree: FBTree, qb, ql, max_items: int = 64,
@@ -841,11 +827,8 @@ def range_scan(tree: FBTree, qb, ql, max_items: int = 64,
     key >= the query (lazy rearrangement: unsorted leaves are sorted on the
     fly, modeling §4.5; ordered leaves skip the sort entirely).
 
-    Dispatches through the engine's scan backend (DESIGN.md §6): a backend
-    with a registered whole-scan kernel (``"fused"`` →
-    ``kernels/fused_scan``) collapses descent + sibling hop + chain walk
-    into one launch; every other backend runs the jnp chain-walk reference
-    (:func:`_range_scan_jnp`), descending through the engine as usual.
+    The engine descends to each start leaf, then the jnp chain walk
+    (:func:`_range_scan_jnp`) follows the sibling links (DESIGN.md §6).
     Returns ``(out_kid [B, max_items], out_val [B, max_items], emitted [B],
     rearranged [B])``; ``rearranged`` (dirty leaves visited) is all-zero
     under a stats-free engine.
@@ -935,8 +918,8 @@ def rebuild(tree: FBTree) -> Tuple[FBTree, BuildReport]:
 
     Gathers the live (key id, value) pairs from the leaves
     (:func:`gather_live_sorted`: packed-word sort, invalid rows last, pool
-    re-packed front-to-back) and reconstructs every level — tuple and
-    stacked layouts alike — through ``fbtree._device_build_from_sorted``.
+    re-packed front-to-back) and reconstructs every level through
+    ``fbtree._device_build_from_sorted``.
     Three jitted stages: marking the live pool rows, the pool sort
     (``fbtree.sort_pool``) and the build core, which is the very program
     ``bulk_build(device=True)`` runs, so a config compiles it once.

@@ -235,9 +235,9 @@ CORRUPTIONS = ("anchor_swap", "chain_break", "high_key", "phantom_slot",
 def _with_levels(tree, levels):
     import jax.numpy as jnp
     from .fbtree import Level
+    # the corrupted host copies go back as the tree's one set of inner
+    # levels; fsck must catch each corruption from the levels alone
     jlv = tuple(Level(*[jnp.asarray(x) for x in lv]) for lv in levels)
-    # deliberately NOT refreshing `stacked`: a real torn write desyncs the
-    # layouts, and fsck's coherence check must catch that too
     return tree.replace(levels=jlv)
 
 

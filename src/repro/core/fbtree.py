@@ -44,7 +44,7 @@ import numpy as np
 from . import keys as K
 
 __all__ = ["TreeConfig", "Level", "FBTree", "bulk_build", "tree_to_device",
-           "leaf_fold", "stack_levels", "chunk_start", "chunk_of_pos",
+           "leaf_fold", "chunk_start", "chunk_of_pos",
            "recompute_inner_meta", "sharded_partition", "sort_pool"]
 
 EMPTY = np.int32(-1)
@@ -84,10 +84,6 @@ class TreeConfig:
       :attr:`leaf_len` leaves: ``leaf_cap + 1`` rounded up to a multiple of
       :attr:`leaf_fold`, the scratch leaf last.
     * ``val_dtype``   leaf value dtype.
-    * ``stacked``     default descent layout for the traversal engine:
-      False = per-level tuple (Python loop), True = stacked
-      ``[n_levels, C_max, ...]`` arrays driven by one ``lax.scan``. Both
-      layouts are always materialized and kept coherent.
     """
     key_width: int
     ns: int = 64           # slots / anchors per node (paper default 64)
@@ -99,10 +95,6 @@ class TreeConfig:
     level_caps: Tuple[int, ...] = (1, 16, 256)
     key_cap: int = 65536
     val_dtype: Any = jnp.int32
-    # default descent layout for the traversal engine: False = per-level
-    # tuple (Python loop), True = stacked [n_levels, C_max, ...] arrays
-    # driven by one lax.scan. Both layouts are always materialized.
-    stacked: bool = False
 
     def __post_init__(self):
         # fail at construction with an actionable message instead of a
@@ -153,7 +145,7 @@ class TreeConfig:
     @staticmethod
     def plan(max_keys: int, key_width: int, ns: int = 64, fs: int = 4,
              leaf_fill: int = 48, inner_fill: int = 48,
-             val_dtype: Any = jnp.int32, stacked: bool = False) -> "TreeConfig":
+             val_dtype: Any = jnp.int32) -> "TreeConfig":
         """Capacity planning: fixed height with min-fanout-16 safety margin.
 
         Guarantees that any key set up to ``max_keys`` (and any tree holding
@@ -174,7 +166,7 @@ class TreeConfig:
                           leaf_fill=min(leaf_fill, ns), inner_fill=min(inner_fill, ns),
                           n_levels=len(caps), leaf_cap=leaf_cap,
                           level_caps=tuple(caps), key_cap=int(max_keys),
-                          val_dtype=val_dtype, stacked=stacked)
+                          val_dtype=val_dtype)
 
 
 class Level(NamedTuple):
@@ -182,9 +174,6 @@ class Level(NamedTuple):
 
     Rows past ``count`` are zeroed pads (``knum=0``,
     ``children=anchors=EMPTY``) that every backend treats as trivial nodes.
-    In the stacked layout (:func:`stack_levels`) the same six arrays gain a
-    leading ``n_levels`` axis and ``count`` becomes an ``int32[n_levels]``
-    vector.
     """
     knum: jnp.ndarray      # int32 [C]
     plen: jnp.ndarray      # int32 [C]
@@ -218,10 +207,8 @@ class TreeArrays(NamedTuple):
 
     * key-pool rows ``[0, key_count)`` hold valid keys; rows at or above the
       watermark (and the scratch row) are zero.
-    * ``levels`` and ``stacked`` describe the same tree: re-deriving
-      ``stacked`` via :func:`stack_levels` is a no-op, and every
-      backend × layout combination descends to identical leaves with
-      identical machine-independent stats (DESIGN.md §3).
+    * every level backend descends to identical leaves with identical
+      machine-independent stats (DESIGN.md §3).
     * each live key id appears in exactly one occupied leaf slot;
       ``leaf_high``/``leaf_next`` order leaves ascending with the last
       leaf's high key ``EMPTY`` (= +inf).
@@ -233,7 +220,6 @@ class TreeArrays(NamedTuple):
     key_tags: jnp.ndarray    # uint8 [KC] hash fingerprints (computed at append)
     key_count: jnp.ndarray   # int32 scalar
     levels: Tuple[Level, ...]
-    stacked: Level           # same levels, stacked+padded to [n_levels, C_max, ...]
     leaf_tags_f: jnp.ndarray   # uint8 [LC // f, f * ns]
     leaf_keyid_f: jnp.ndarray  # int32 [LC // f, f * ns] (-1 empty)
     leaf_val_f: jnp.ndarray    # val_dtype [LC // f, f * ns]
@@ -298,36 +284,6 @@ class FBTree:
     @property
     def n_keys_live(self) -> int:
         return int(jnp.sum(self.arrays.leaf_occ_f))
-
-
-def stack_levels(levels: Tuple[Level, ...]) -> Level:
-    """Stack per-level arrays into one padded [n_levels, C_max, ...] Level.
-
-    Rows past a level's own cap are knum=0 / children=anchors=EMPTY, so a
-    backend treats them as trivial nodes (well-formed descents never land on
-    them). ``count`` becomes an int32 [n_levels] vector. Pure jnp: callable
-    under jit, so mutating ops can refresh the stacked copy in-graph. This is
-    the level-synchronous layout the ``lax.scan`` descent consumes
-    (DESIGN.md §3); both builders materialize it alongside ``levels``.
-    """
-    C_max = max(l.knum.shape[0] for l in levels)
-
-    def pad(a, fillv):
-        short = C_max - a.shape[0]
-        if short == 0:
-            return a
-        return jnp.concatenate(
-            [a, jnp.full((short,) + a.shape[1:], fillv, a.dtype)], axis=0)
-
-    return Level(
-        knum=jnp.stack([pad(l.knum, 0) for l in levels]),
-        plen=jnp.stack([pad(l.plen, 0) for l in levels]),
-        prefix=jnp.stack([pad(l.prefix, 0) for l in levels]),
-        features=jnp.stack([pad(l.features, 0) for l in levels]),
-        children=jnp.stack([pad(l.children, EMPTY) for l in levels]),
-        anchors=jnp.stack([pad(l.anchors, EMPTY) for l in levels]),
-        count=jnp.stack([l.count for l in levels]),
-    )
 
 
 # --------------------------------------------------------------------------
@@ -473,9 +429,9 @@ def bulk_build(cfg: TreeConfig, ks: K.KeySet, vals: np.ndarray,
     sort via packed-word argsorts (:func:`_sort_raw`), build leaves and
     every inner level with segmented jnp reductions
     (:func:`recompute_inner_meta`), the only Python loop being over the
-    O(log n) height. Both paths produce
-    bit-identical :class:`TreeArrays` (including the stacked layout) — the
-    equivalence tests in ``tests/test_tree_ops.py`` pin this contract.
+    O(log n) height. Both paths produce bit-identical :class:`TreeArrays`
+    — the equivalence tests in ``tests/test_tree_ops.py`` pin this
+    contract.
 
     Shapes: ``ks.bytes`` is ``uint8 [n, key_width]``, ``ks.lens`` ``int32
     [n]``, ``vals`` ``[n]`` (cast to ``cfg.val_dtype``). Raises on capacity
@@ -584,7 +540,6 @@ def bulk_build(cfg: TreeConfig, ks: K.KeySet, vals: np.ndarray,
         key_tags=jnp.asarray(ktags),
         key_count=jnp.asarray(np.int32(n)),
         levels=tuple(levels),
-        stacked=stack_levels(tuple(levels)),
         leaf_tags_f=fold(leaf_tags), leaf_keyid_f=fold(leaf_keyid),
         leaf_val_f=fold(leaf_val).astype(cfg.val_dtype),
         leaf_occ_f=fold(leaf_occ),
@@ -696,7 +651,6 @@ def _device_build_from_sorted(cfg: TreeConfig, kb, kl, ktags, vals, n):
         key_bytes=kb, key_lens=kl, key_tags=ktags,
         key_count=n,
         levels=levels,
-        stacked=stack_levels(levels),
         leaf_tags_f=leaf_tags, leaf_keyid_f=leaf_keyid, leaf_val_f=leaf_val,
         leaf_occ_f=lvalid,
         leaf_high=leaf_high, leaf_next=leaf_next,

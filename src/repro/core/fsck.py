@@ -4,7 +4,7 @@
 on a *simulated* tree; this module ports the same invariants — chain
 order, high-key coverage, accounting — to the actual device arrays, plus
 everything the structure-of-arrays layout adds (anchor order, DFS
-reachability, meta coherence, stacked/tuple layout agreement). It is the
+reachability, meta coherence). It is the
 gate :class:`core.lifecycle.TreeVersionManager` runs on every staged tree
 before a publish swap: a corrupted or half-built version can never become
 the serving version.
@@ -33,9 +33,8 @@ it detectable):
    ``[lo, hi)`` anchor bounds.
 8. ``plen``/``prefix``/``features`` equal ``recompute_inner_meta`` of the
    anchors (the §3 SIMD metadata is derived state — it must agree).
-9. ``stacked`` equals ``stack_levels(levels)`` (layout coherence).
-10. ``leaf_version`` >= 0, and (vs an optional ``prev`` snapshot) versions
-    never regress on surviving leaves — §4.2 monotonicity.
+9. ``leaf_version`` >= 0, and (vs an optional ``prev`` snapshot) versions
+   never regress on surviving leaves — §4.2 monotonicity.
 """
 from __future__ import annotations
 
@@ -43,7 +42,7 @@ from typing import List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
-from .fbtree import FBTree, stack_levels
+from .fbtree import FBTree
 
 __all__ = ["FsckReport", "check_tree", "check_sharded", "check",
            "assert_ok"]
@@ -187,7 +186,7 @@ def check_tree(tree: FBTree, name: str = "tree",
                 bad(f"leaf {i}: marked ordered but slots are not "
                     f"ascending")
 
-    # ---- 10: versions ----
+    # ---- 9: versions ----
     if (ver[:leaf_count] < 0).any():
         bad("negative leaf version")
     if prev is not None and prev.config == cfg:
@@ -314,14 +313,6 @@ def check_tree(tree: FBTree, name: str = "tree",
                                           levels[li]["features"][:cnt])):
                 bad(f"level {li}: plen/prefix/features disagree with "
                     f"recompute_inner_meta of the anchors")
-
-    # ---- 9: stacked/tuple layout coherence ----
-    st = stack_levels(a.levels)
-    for f in st._fields:
-        if not np.array_equal(np.asarray(getattr(st, f)),
-                              np.asarray(getattr(a.stacked, f))):
-            bad(f"stacked layout field {f!r} out of sync with levels")
-            break
 
     return FsckReport(ok=not v, violations=tuple(v), n_live=n_live,
                       n_leaves=leaf_count)

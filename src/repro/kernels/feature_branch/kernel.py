@@ -53,10 +53,8 @@ def auto_tile(B: int, cap: int, floor: int = 8) -> int:
 def feature_compare_rounds(feats, qfeat, knum, pcmp, *, fs: int, ns: int,
                            collect_stats: bool):
     """The in-kernel feature-comparison round loop (paper Fig. 6 l.7-19),
-    [TB, 1]-keepdims masked-iota formulation. SHARED between the per-level
-    kernel below and the fused whole-descent kernel
-    (``kernels/fused_descent``) — the parity contract requires both to be
-    bit-identical, so there is exactly one definition.
+    [TB, 1]-keepdims masked-iota formulation, used by the per-level kernel
+    below.
 
     Returns ``(idx, resolved, run_lo, run_hi, rounds)``, all int32
     ``[TB, 1]``; the prefix/trivial overrides are folded in (``resolved``

@@ -37,7 +37,6 @@ class ServeConfig:
     max_new_tokens: int = 32
     # traversal engine for the prefix-cache tree (None -> core default)
     tree_backend: Optional[str] = None
-    tree_layout: Optional[str] = None
     # prefix-cache tree shards (>1 routes through repro.shard, DESIGN.md §7)
     tree_shards: int = 1
     # fault-injection plan for the cache's lifecycle + shard dispatch
@@ -65,9 +64,8 @@ class Engine:
         self.pos = np.zeros(scfg.max_batch, np.int32)
         self.live: List[Optional[Request]] = [None] * scfg.max_batch
         # serving is throughput-only: run the tree stats-free (DESIGN.md §3)
-        tree_engine = (TraversalEngine(scfg.tree_backend or "jnp",
-                                       scfg.tree_layout, collect_stats=False)
-                       if (scfg.tree_backend or scfg.tree_layout) else None)
+        tree_engine = (TraversalEngine(scfg.tree_backend, collect_stats=False)
+                       if scfg.tree_backend else None)
         self.prefix = PrefixCache(scfg.n_pages, scfg.block_tokens,
                                   engine=tree_engine,
                                   n_shards=scfg.tree_shards,

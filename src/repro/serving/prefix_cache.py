@@ -11,10 +11,8 @@ All cache operations are *batched tree ops* on the FB+-tree core:
   touch            -> update_batch on access stamps (the paper's latch-free
                       update path: value CAS, version untouched, readers
                       never restart)
-  evict sweep      -> range_scan over the digest space (scan engine,
-                      DESIGN.md §6: dispatches to the fused scan kernel
-                      when the cache's engine backend registers one, else
-                      the jnp chain walk; leaves the cache keeps ordered
+  evict sweep      -> range_scan over the digest space (the jnp chain
+                      walk, DESIGN.md §6; leaves the cache keeps ordered
                       ride the lazy-rearrangement fast path)
   compact          -> rebuild (device-side bulk build, DESIGN.md §5) run
                       as an atomic fsck-gated publish through
@@ -88,9 +86,7 @@ class PrefixCache:
         self.n_shards = int(n_shards)
         self.faults = faults
         self.retry = retry
-        cfg = TreeConfig.plan(
-            max_keys=max_keys, key_width=KEY_W,
-            stacked=(engine is not None and engine.layout == "stacked"))
+        cfg = TreeConfig.plan(max_keys=max_keys, key_width=KEY_W)
         if self.n_shards > 1:
             from repro import shard as SH
             self._shard = SH

@@ -64,7 +64,7 @@ def sharded_build(ks: K.KeySet, vals, n_shards: int,
     * ``presorted``           the keys are already in the global sort
       order — skip step 1's sort (rebalance's concatenated snapshots).
     * ``plan_kw``             forwarded to ``TreeConfig.plan`` (ns, fs,
-      leaf_fill, val_dtype, stacked, ...).
+      leaf_fill, val_dtype, ...).
     """
     if n_shards < 1:
         raise ValueError(

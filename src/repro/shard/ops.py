@@ -5,9 +5,9 @@ host's CPU device, where the router lives, over the host copy of the keys
 (no accelerator involved; a device batch is fetched first). A host loop
 then launches ONE jitted shard-local op per shard that owns work — the
 same ``core.batch_ops`` entry points every unsharded call site uses,
-through the same ``TraversalEngine`` (any backend/layout, including the
-fused kernels). Launches are asynchronous per device, so with a
-multi-device mesh the shards genuinely overlap. A
+through the same ``TraversalEngine`` (any level backend). Launches are
+asynchronous per device, so with a multi-device mesh the shards
+genuinely overlap. A
 lookup's results are copied device to device to shard 0's device and
 selected by owner there, in one small jitted program, and returned as
 device arrays whose host copies are already under way: for a host batch
@@ -423,10 +423,9 @@ def range_scan(st: ShardedTree, qb, ql, max_items: int = 64,
     across shards (leaf chunking differs per partition, so it is *not*
     parity-comparable).
 
-    Each per-shard scan goes through the engine's §6 scan path (fused
-    kernel or jnp chain walk) and keeps its lazy-rearrangement ordering
-    guarantee; the merge is pure concatenation because the partition is by
-    key range.
+    Each per-shard scan goes through the §6 jnp chain walk and keeps its
+    lazy-rearrangement ordering guarantee; the merge is pure concatenation
+    because the partition is by key range.
 
     Degradation: a lane whose next needed shard is unreachable is marked
     ``failed`` and stops there — its emissions so far are a correct

@@ -82,51 +82,6 @@ def test_leaf_probe_kernel(rng):
     assert (f1 == f2).all() and (s1 == s2).all() and (v1 == v2).all()
 
 
-def test_fused_descent_kernel_matches_ref(rng):
-    """The fused whole-descent kernel vs its composed-primitives oracle
-    (kernels/fused_descent/ref.py): same leaves, paths, probe results, and
-    stats — in both stats modes and with/without the sibling epilogue."""
-    from repro.kernels.fused_descent.ops import (fused_traverse,
-                                                 fused_traverse_probe)
-    from repro.kernels.fused_descent.ref import (fused_traverse_probe_ref,
-                                                 fused_traverse_ref)
-    n = 800
-    ints = rng.choice(2**48, size=n, replace=False)
-    ks = K.make_keyset([int(x) for x in ints], 10)
-    cfg = TreeConfig.plan(max_keys=2 * n, key_width=10)
-    t = bulk_build(cfg, ks, np.arange(n, dtype=np.int32))
-    qb = np.array(ks.bytes[:192])
-    qb[::4, -1] ^= 0x5A                      # mix in missing keys
-    qb, ql = jnp.asarray(qb), jnp.asarray(ks.lens[:192])
-
-    for sibling in (True, False):
-        for cs in (True, False):
-            leaf_r, path_r, st_r = fused_traverse_ref(
-                t, qb, ql, sibling_check=sibling, collect_stats=cs)
-            leaf_k, path_k, st_k = fused_traverse(
-                t, qb, ql, sibling_check=sibling, collect_stats=cs)
-            assert (np.asarray(leaf_k) == np.asarray(leaf_r)).all()
-            for p, rp in zip(path_k, path_r):
-                assert (np.asarray(p) == np.asarray(rp)).all()
-            if cs:
-                for f in st_r._fields:
-                    assert (np.asarray(getattr(st_k, f))
-                            == np.asarray(getattr(st_r, f))).all(), f
-    outs_r = fused_traverse_probe_ref(t, qb, ql)
-    outs_k = fused_traverse_probe(t, qb, ql)
-    for name, r, k in zip(("leaf", "path", "found", "slot", "val"),
-                          outs_r[:5], outs_k[:5]):
-        if name == "path":
-            for p, rp in zip(k, r):
-                assert (np.asarray(p) == np.asarray(rp)).all()
-        else:
-            assert (np.asarray(k) == np.asarray(r)).all(), name
-    for st_r, st_k in zip(outs_r[5:], outs_k[5:]):
-        for f in st_r._fields:
-            assert (np.asarray(getattr(st_k, f))
-                    == np.asarray(getattr(st_r, f))).all(), f
-
-
 # ---------------------------------------------------------------- flash attn
 def test_flash_attention_kernel_sweep(rng):
     import jax
@@ -174,8 +129,7 @@ def test_flash_sdpa_gqa_and_grads(rng):
 def test_interpret_mode_follows_the_backend(monkeypatch, backend,
                                             interprets):
     """Kernels interpret only on the CPU and compile on a TPU; any other
-    backend raises instead of hiding the device behind the interpreter.
-    ``fused`` is refused on a TPU at engine construction."""
+    backend raises instead of hiding the device behind the interpreter."""
     import jax
     from repro.core.traverse import TraversalEngine
     from repro.kernels import interpret_mode
@@ -186,8 +140,3 @@ def test_interpret_mode_follows_the_backend(monkeypatch, backend,
     else:
         assert interpret_mode() is interprets
     TraversalEngine("pallas")
-    if backend == "tpu":
-        with pytest.raises(ValueError, match="do not compile for a TPU"):
-            TraversalEngine("fused")
-    else:
-        TraversalEngine("fused")

@@ -101,7 +101,7 @@ def test_drained_counters_match_branchstats_totals():
     the parity suite asserts on directly — one device_get, no drift."""
     tree, base = _tree()
     q = _keyset([int(x) for x in base[:96]])
-    eng = TraversalEngine("jnp", "tuple", collect_stats=True)
+    eng = TraversalEngine("jnp", collect_stats=True)
     _, rep = B.lookup_batch(tree, q.bytes, q.lens, engine=eng)
 
     obs.enable()
@@ -403,13 +403,11 @@ def test_ops_add_no_host_sync_with_telemetry_off(monkeypatch):
         B.lookup_batch(tree, q.bytes, q.lens)
 
 
-@pytest.mark.parametrize("layout", ["tuple", "stacked"])
-def test_programs_carry_named_scopes_and_match_across_flag(layout):
+def test_programs_carry_named_scopes_and_match_across_flag():
     """The lookup and update programs carry the ``descent`` and
     ``leaf_probe`` scopes in their ``op_name`` metadata (per inner level
-    ``level_<l>`` under the tuple layout, one scan body when stacked), and
-    their lowered text, debug locations included, does not change with
-    the obs flag."""
+    ``level_<l>``), and their lowered text, debug locations included, does
+    not change with the obs flag."""
     import re
 
     import jax.numpy as jnp
@@ -417,7 +415,7 @@ def test_programs_carry_named_scopes_and_match_across_flag(layout):
     q = _keyset([int(x) for x in base[:32]])
     qb, ql = jnp.asarray(q.bytes), jnp.asarray(q.lens)
     vals = jnp.arange(32, dtype=jnp.int32)
-    eng = TraversalEngine("jnp", layout, collect_stats=False)
+    eng = TraversalEngine("jnp", collect_stats=False)
     progs = {"_lookup_batch_jit": lambda: B._lookup_batch_jit.lower(
                  tree, qb, ql, sibling_check=True, engine=eng),
              "_update_batch_jit": lambda: B._update_batch_jit.lower(
@@ -432,5 +430,4 @@ def test_programs_carry_named_scopes_and_match_across_flag(layout):
                                 lower().compile().as_text()))
         assert any(s.startswith("descent/") for s in scopes), name
         assert any(s.startswith("leaf_probe/") for s in scopes), name
-        per_level = any(s.startswith("descent/level_0/") for s in scopes)
-        assert per_level == (layout == "tuple"), name
+        assert any(s.startswith("descent/level_0/") for s in scopes), name

@@ -1,26 +1,22 @@
-"""Scan-engine contract (DESIGN.md §6): every scan route — the jnp
-chain-walk reference under any descent backend, the always-sort baseline,
-and the fused whole-scan kernel — emits bit-identical ``(key_id, value)``
+"""Scan contract (DESIGN.md §6): the jnp chain walk under any level
+backend and the always-sort baseline emit bit-identical ``(key_id, value)``
 pairs, ascending, starting at the first key >= the query, on ordered and
 dirty (lazily-rearranged) leaves alike; the early-exit walk drains chains
 completely when ``max_items`` exceeds the live key count; ``rearranged``
 counts exactly the dirty leaves visited and compiles away stats-free."""
 import jax.numpy as jnp
 import numpy as np
-import pytest
 from hypothesis import HealthCheck, given, settings, strategies as st
 
 from repro.core import batch_ops as B
 from repro.core import keys as K
 from repro.core.fbtree import EMPTY, TreeConfig, bulk_build
-from repro.core.traverse import TraversalEngine, get_scan_backend
+from repro.core.traverse import TraversalEngine
 
 from benchmarks.common import make_dataset
 
-# the scan A/B matrix: jnp reference under both layouts, a level-kernel
-# descent feeding the reference walk, and the fused whole-scan kernel
-SCAN_COMBOS = [("jnp", "tuple"), ("jnp", "stacked"), ("pallas", "tuple"),
-               ("fused", "stacked")]
+# the scan's descent under the jnp engine and under the Pallas level kernel
+SCAN_BACKENDS = ("jnp", "pallas")
 
 
 def _build_churned(ds_name, n_keys, seed, dirty=True):
@@ -73,8 +69,7 @@ def _expected(tree, qb_row, ql_row, max_items):
 @given(st.sampled_from(("rand-int", "ycsb", "url")), st.booleans(),
        st.integers(0, 2**31 - 1))
 def test_scan_backend_parity(ds_name, dirty, seed):
-    """jnp × layouts × pallas-descent × fused kernel, ordered and dirty
-    trees: identical pairs, ascending, starting at the first key >= query,
+    """jnp and pallas descents, ordered and dirty trees: identical pairs, ascending, starting at the first key >= query,
     EMPTY past ``emitted``; ``rearranged`` agrees across backends."""
     tree, width = _build_churned(ds_name, 400, seed % 1000, dirty=dirty)
     a = tree.arrays
@@ -90,8 +85,8 @@ def test_scan_backend_parity(ds_name, dirty, seed):
     M = 32
 
     ref = None
-    for backend, layout in SCAN_COMBOS:
-        eng = TraversalEngine(backend=backend, layout=layout)
+    for backend in SCAN_BACKENDS:
+        eng = TraversalEngine(backend=backend)
         kid, val, em, rearr = B.range_scan(tree, qb, ql, max_items=M,
                                            engine=eng)
         sig = tuple(np.asarray(x) for x in (kid, val, em, rearr))
@@ -109,9 +104,9 @@ def test_scan_backend_parity(ds_name, dirty, seed):
         else:
             for got, want, nm in zip(sig, ref,
                                      ("kid", "val", "emitted", "rearranged")):
-                assert (got == want).all(), (backend, layout, nm)
+                assert (got == want).all(), (backend, nm)
         if not dirty:
-            assert (sig[3] == 0).all(), (backend, layout, "rearranged clean")
+            assert (sig[3] == 0).all(), (backend, "rearranged clean")
 
 
 @settings(deadline=None, max_examples=6,
@@ -153,7 +148,7 @@ def test_scan_drains_short_chains():
     live = np.sort(ints[700:].astype(np.uint64))
 
     s0 = K.make_keyset([int(live[0])], KW)
-    for eng in (TraversalEngine("jnp"), TraversalEngine("fused")):
+    for eng in (TraversalEngine("jnp"), TraversalEngine("pallas")):
         kid, val, em, _ = B.range_scan(t, s0.bytes, s0.lens, max_items=256,
                                        engine=eng)
         assert int(em[0]) == len(live), (eng.backend, int(em[0]), len(live))
@@ -165,8 +160,8 @@ def test_scan_drains_short_chains():
 def test_scan_rearranged_accounting():
     """``rearranged`` counts the dirty leaves a lane actually visited —
     across ALL hops (the old code only billed hop 0) — is zero on a fresh
-    bulk-built tree, zero under a stats-free engine, and identical between
-    the jnp reference and the fused kernel."""
+    bulk-built tree, zero under a stats-free engine, and identical under
+    the jnp and pallas descents."""
     KW = 12
     keys = [int(x) for x in range(0, 4000, 4)]
     ks = K.make_keyset(keys, KW)
@@ -188,42 +183,18 @@ def test_scan_rearranged_accounting():
     assert n_dirty == 1
     _, _, _, r_jnp = B.range_scan(t2, s.bytes, s.lens, max_items=200)
     assert int(r_jnp[0]) == 1                      # billed on a later hop
-    _, _, _, r_fused = B.range_scan(t2, s.bytes, s.lens, max_items=200,
-                                    engine=TraversalEngine("fused"))
-    assert (np.asarray(r_fused) == np.asarray(r_jnp)).all()
+    _, _, _, r_pallas = B.range_scan(t2, s.bytes, s.lens, max_items=200,
+                                     engine=TraversalEngine("pallas"))
+    assert (np.asarray(r_pallas) == np.asarray(r_jnp)).all()
     # a scan starting past the dirty leaf never visits it
     s2 = K.make_keyset([2000], KW)
     _, _, _, r_far = B.range_scan(t2, s2.bytes, s2.lens, max_items=64)
     assert int(r_far[0]) == 0
     # stats-free engines compile the counter away
-    for backend in ("jnp", "fused"):
+    for backend in SCAN_BACKENDS:
         _, _, em_off, r_off = B.range_scan(
             t2, s.bytes, s.lens, max_items=200,
             engine=TraversalEngine(backend, collect_stats=False))
         assert int(em_off[0]) == 200, backend
         assert (np.asarray(r_off) == 0).all(), backend
 
-
-def test_scan_registry():
-    """Registry contract: ``fused`` exposes a whole-scan entry, level
-    backends fall back to the jnp reference (scan_path is None), and the
-    kernel-level oracle (``kernels.fused_scan.ref``) matches the registered
-    kernel entry outside the engine dispatch."""
-    assert callable(get_scan_backend("fused"))
-    assert TraversalEngine("fused").scan_path() is not None
-    assert TraversalEngine("jnp").scan_path() is None
-    assert TraversalEngine("pallas").scan_path() is None
-    assert TraversalEngine("binary").scan_path() is None
-    with pytest.raises(KeyError):
-        get_scan_backend("no-such-scan-backend")
-
-    from repro.kernels.fused_scan.ops import fused_range_scan
-    from repro.kernels.fused_scan.ref import fused_range_scan_ref
-    tree, _ = _build_churned("ycsb", 200, 5, dirty=True)
-    kid_s, kb_s, kl_s, _ = _oracle(tree)
-    qb = jnp.asarray(kb_s[::40][:8])
-    ql = jnp.asarray(kl_s[::40][:8])
-    got = fused_range_scan(tree, qb, ql, max_items=16)
-    want = fused_range_scan_ref(tree, qb, ql, max_items=16)
-    for g, w, nm in zip(got, want, ("kid", "val", "emitted", "rearranged")):
-        assert (np.asarray(g) == np.asarray(w)).all(), nm

@@ -26,11 +26,9 @@ from repro.core.traverse import TraversalEngine
 from benchmarks.common import make_dataset
 
 SHARD_COUNTS = (1, 2, 4)
-# jnp reference engine + the fused whole-descent/whole-scan kernel — the
-# two extremes of the backend matrix (pallas rides the same level-backend
-# path as jnp through the engine)
-ENGINES = (TraversalEngine("jnp"),
-           TraversalEngine("fused", layout="stacked"))
+# shard dispatch passes the engine through untouched; engine parity is
+# tests/test_traverse_parity.py's job
+ENGINES = (TraversalEngine("jnp"),)
 
 
 def _dataset_tree(ds_name, n_keys, seed, dirty=False):

@@ -171,6 +171,49 @@ def test_ns64_leaf_store_is_used_in_place_on_v5e(one_chip, tree64, op):
     assert not loops, loops
 
 
+TREE_PARAM = re.compile(r"%tree_0__(\w+?)\.\d+ = \w+\[([\d,]*)\]\S* parameter\(")
+LEVEL_PARAM = re.compile(r"levels_(\d+)__(\w+)")
+
+
+@pytest.mark.parametrize("op", ["lookup", "update"])
+def test_ns64_program_takes_each_inner_level_once_on_v5e(one_chip, tree64,
+                                                         op):
+    """The deployed-size lookup and update take the inner levels once, as
+    per-level arrays: the tree argument is ``4 + 7 * n_levels + 9`` arrays
+    (the key pool's four, each level's seven, the leaves' nine), and every
+    tree parameter of the v5e-compiled program is a flat field or one
+    level's own array, sized to that level. The update returns the whole
+    tree, so its program takes every one of those arrays."""
+    cfg = tree64.config
+    n_tree = 4 + 7 * cfg.n_levels + 9
+    qb, ql = _queries(one_chip, 4096)
+    if op == "lookup":
+        low = B._lookup_batch_jit.lower(tree64, qb, ql, True, STATS_OFF)
+    else:
+        low = B._update_batch_jit.lower(tree64, qb, ql,
+                                        _sds(one_chip, (4096,), jnp.int32),
+                                        STATS_OFF)
+    assert len(jax.tree_util.tree_leaves(low.args_info[0][0])) == n_tree
+    text = low.compile().as_text()
+    entry = text[text.index("\nENTRY "):]
+    params = TREE_PARAM.findall(entry[:entry.index("\n}\n")])
+    assert params
+    flat = set(type(tree64.arrays)._fields) - {"levels"}
+    for name, dims in params:
+        m = LEVEL_PARAM.fullmatch(name)
+        if m is None:
+            assert name in flat, name
+            continue
+        lvl = int(m.group(1))
+        assert lvl < cfg.n_levels, name
+        if m.group(2) != "count":
+            rows = int(dims.split(",")[0])
+            assert rows == cfg.level_caps[lvl] + 1, (name, dims)
+    assert len({name for name, _ in params}) == len(params)
+    if op == "update":
+        assert len(params) == n_tree
+
+
 def test_build_and_rebuild_sort_compiles_for_v5e(one_chip, tree):
     """The device build's sort of the raw keys and ``rebuild``'s sort of
     the key pool."""

@@ -1,10 +1,9 @@
-"""Engine contract: every (backend, layout) combination is observationally
-identical — same children at every level, same leaf ids, same
-machine-independent BranchStats — on randomized trees drawn from the
-benchmark dataset distributions. The matrix includes both backend kinds
-(per-level and the ``fused`` whole-descent kernel) and both stats modes:
-``collect_stats=False`` must return bit-identical leaf ids/paths while
-compiling the counters away (DESIGN.md §3)."""
+"""Engine contract: every level backend is observationally identical —
+same children at every level, same leaf ids, same machine-independent
+BranchStats — on randomized trees drawn from the benchmark dataset
+distributions, in both stats modes: ``collect_stats=False`` must return
+bit-identical leaf ids/paths while compiling the counters away
+(DESIGN.md §3)."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -12,15 +11,17 @@ from hypothesis import HealthCheck, given, settings, strategies as st
 
 from repro.core import batch_ops as B
 from repro.core import keys as K
-from repro.core.fbtree import TreeConfig, bulk_build, stack_levels
+from repro.core import fsck
+from repro.core.fbtree import TreeConfig, bulk_build
 from repro.core.traverse import (DEFAULT_ENGINE, TraversalEngine,
-                                 available_backends, backend_kind,
-                                 get_backend, get_descent_backend)
+                                 available_backends, get_backend)
 
 from benchmarks.common import make_dataset
 
-COMBOS = ([(b, l) for b in ("jnp", "pallas") for l in ("tuple", "stacked")]
-          + [("fused", "stacked")])
+# the backends whose machine-independent counters follow the paper's
+# algorithm (the binary-search baselines count their own)
+BACKENDS = ("jnp", "pallas")
+LEVEL_BACKENDS = ("jnp", "pallas", "binary", "binary+prefix")
 
 STAT_FIELDS = ("feat_rounds", "suffix_bs", "key_compares", "sibling_hops")
 
@@ -49,12 +50,12 @@ def test_backend_layout_parity(ds_name, fs, seed):
     qb, ql = jnp.asarray(qb), jnp.asarray(ql)
 
     results = {}
-    for backend, layout in COMBOS:
-        eng = TraversalEngine(backend=backend, layout=layout)
+    for backend in BACKENDS:
+        eng = TraversalEngine(backend=backend)
         leaf, path, stats = eng.traverse(tree, qb, ql)
-        results[(backend, layout)] = (np.asarray(leaf),
-                                      [np.asarray(p) for p in path], stats)
-    ref_leaf, ref_path, ref_stats = results[("jnp", "tuple")]
+        results[backend] = (np.asarray(leaf),
+                            [np.asarray(p) for p in path], stats)
+    ref_leaf, ref_path, ref_stats = results["jnp"]
     for combo, (leaf, path, stats) in results.items():
         assert (leaf == ref_leaf).all(), (combo, "leaf ids")
         for lvl, (p, rp) in enumerate(zip(path, ref_path)):
@@ -73,9 +74,9 @@ def test_lookup_reports_identical_across_engines(ds_name, seed):
     qb = jnp.asarray(ks.bytes[:128])
     ql = jnp.asarray(ks.lens[:128])
     ref = None
-    for backend, layout in COMBOS:
+    for backend in BACKENDS:
         vals, rep = B.lookup_batch(tree, qb, ql,
-                                   engine=TraversalEngine(backend, layout))
+                                   engine=TraversalEngine(backend))
         sig = (np.asarray(vals), np.asarray(rep.found),
                np.asarray(rep.key_compares), np.asarray(rep.suffix_bs),
                np.asarray(rep.feat_rounds))
@@ -83,42 +84,44 @@ def test_lookup_reports_identical_across_engines(ds_name, seed):
             ref = sig
             assert sig[1].all()   # all present keys found
         for a, b in zip(ref, sig):
-            assert (a == b).all(), (backend, layout)
+            assert (a == b).all(), backend
 
 
-def test_stacked_matches_tuple_after_inserts():
-    """The stacked copy must track the tuple levels through split rounds."""
+def test_split_rounds_keep_engines_in_step():
+    """Inserts that split leaves rewrite the inner levels in place: after
+    them every key is found, the ``jnp`` and ``pallas`` engines return the
+    same values, and the tree passes fsck."""
     KW = 12
     keys = [int(x) for x in range(0, 3000, 3)]
     ks0 = K.make_keyset(keys[:100], KW)
-    cfg = TreeConfig.plan(max_keys=8192, key_width=KW, stacked=True)
+    cfg = TreeConfig.plan(max_keys=8192, key_width=KW)
     t = bulk_build(cfg, ks0, np.arange(100, dtype=np.int32))
     ks = K.make_keyset(keys[100:], KW)
     t, rep, _ = B.insert_batch(t, ks.bytes, ks.lens,
-                               np.arange(100, 1000, dtype=np.int32),
-                               engine=TraversalEngine("jnp", "stacked"))
+                               np.arange(100, 1000, dtype=np.int32))
     assert int(rep.splits) > 0
-    restacked = stack_levels(t.arrays.levels)
-    for got, want in zip(t.arrays.stacked, restacked):
-        assert (np.asarray(got) == np.asarray(want)).all()
     allk = K.make_keyset(keys, KW)
-    v_t, r_t = B.lookup_batch(t, allk.bytes, allk.lens,
-                              engine=TraversalEngine("jnp", "tuple"))
-    v_s, r_s = B.lookup_batch(t, allk.bytes, allk.lens,
-                              engine=TraversalEngine("pallas", "stacked"))
-    assert np.asarray(r_t.found).all() and np.asarray(r_s.found).all()
-    assert (np.asarray(v_t) == np.asarray(v_s)).all()
+    v_j, r_j = B.lookup_batch(t, allk.bytes, allk.lens,
+                              engine=TraversalEngine("jnp"))
+    v_p, r_p = B.lookup_batch(t, allk.bytes, allk.lens,
+                              engine=TraversalEngine("pallas"))
+    assert np.asarray(r_j.found).all() and np.asarray(r_p.found).all()
+    assert (np.asarray(v_j) == np.arange(len(keys))).all()
+    assert (np.asarray(v_p) == np.asarray(v_j)).all()
+    report = fsck.check_tree(t)
+    assert report.ok, report.violations
 
 
+@pytest.mark.parametrize("backend", LEVEL_BACKENDS)
 @settings(deadline=None, max_examples=6,
           suppress_health_check=list(HealthCheck))
 @given(st.sampled_from(("rand-int", "3-gram", "ycsb", "twitter", "url")),
        st.integers(0, 2**31 - 1))
-def test_device_built_tree_parity(ds_name, seed):
+def test_device_built_tree_parity(backend, ds_name, seed):
     """A device-built tree is traversal-equivalent to the host-built tree
-    across ALL backend × layout combinations (DESIGN.md §5): same leaves,
-    same per-level children, and — for the stats-contract backends — the
-    same machine-independent counters as the host-tree reference."""
+    under every level backend (DESIGN.md §5): same leaves, same per-level
+    children and the same machine-independent counters; the
+    stats-contract backends also reach the ``jnp`` engine's leaves."""
     keys, width = make_dataset(ds_name, 500, seed=seed % 1000)
     ks = K.make_keyset(keys, width)
     cfg = TreeConfig.plan(max_keys=2 * len(keys), key_width=width)
@@ -134,37 +137,27 @@ def test_device_built_tree_parity(ds_name, seed):
     qb[flip, -1] ^= 0xA5
     qb, ql = jnp.asarray(qb), jnp.asarray(ql)
 
-    ref_leaf = None
-    all_combos = ([(b, l) for b in ("jnp", "pallas", "binary",
-                                    "binary+prefix")
-                   for l in ("tuple", "stacked")] + [("fused", "stacked")])
-    for backend, layout in all_combos:
-        eng = TraversalEngine(backend, layout)
-        h_leaf, h_path, h_stats = eng.traverse(th, qb, ql)
-        d_leaf, d_path, d_stats = eng.traverse(td, qb, ql)
-        assert (np.asarray(d_leaf) == np.asarray(h_leaf)).all(), \
-            (backend, layout, "leaf ids")
-        for lvl, (p, rp) in enumerate(zip(d_path, h_path)):
-            assert (np.asarray(p) == np.asarray(rp)).all(), \
-                (backend, layout, "children at level", lvl)
-        for f in STAT_FIELDS:
-            assert (np.asarray(getattr(d_stats, f))
-                    == np.asarray(getattr(h_stats, f))).all(), \
-                (backend, layout, f)
-        # stats-contract backends also agree with each other on leaf ids
-        if (backend, layout) in COMBOS:
-            if ref_leaf is None:
-                ref_leaf = np.asarray(d_leaf)
-            assert (np.asarray(d_leaf) == ref_leaf).all(), (backend, layout)
+    eng = TraversalEngine(backend)
+    h_leaf, h_path, h_stats = eng.traverse(th, qb, ql)
+    d_leaf, d_path, d_stats = eng.traverse(td, qb, ql)
+    assert (np.asarray(d_leaf) == np.asarray(h_leaf)).all(), "leaf ids"
+    for lvl, (p, rp) in enumerate(zip(d_path, h_path)):
+        assert (np.asarray(p) == np.asarray(rp)).all(), \
+            ("children at level", lvl)
+    for f in STAT_FIELDS:
+        assert (np.asarray(getattr(d_stats, f))
+                == np.asarray(getattr(h_stats, f))).all(), f
+    if backend in BACKENDS:
+        ref_leaf, _, _ = DEFAULT_ENGINE.traverse(th, qb, ql)
+        assert (np.asarray(d_leaf) == np.asarray(ref_leaf)).all()
 
 
 def test_rebuild_preserves_engine_parity():
-    """After churn + rebuild, every backend × layout still agrees — the
-    rebuilt stacked copy must equal re-deriving it from the tuple levels."""
+    """After churn + rebuild, every backend still agrees."""
     KW = 12
     keys = [int(x) for x in range(0, 3000, 3)]
     ks0 = K.make_keyset(keys[:100], KW)
-    cfg = TreeConfig.plan(max_keys=8192, key_width=KW, stacked=True)
+    cfg = TreeConfig.plan(max_keys=8192, key_width=KW)
     t = bulk_build(cfg, ks0, np.arange(100, dtype=np.int32))
     ks = K.make_keyset(keys[100:], KW)
     t, rep, _ = B.insert_batch(t, ks.bytes, ks.lens,
@@ -174,21 +167,17 @@ def test_rebuild_preserves_engine_parity():
     t, _ = B.remove_batch(t, rmk.bytes, rmk.lens)
     t, brep = B.rebuild(t)
     assert not bool(brep.error)
-    restacked = stack_levels(t.arrays.levels)
-    for got, want in zip(t.arrays.stacked, restacked):
-        assert (np.asarray(got) == np.asarray(want)).all()
     allk = K.make_keyset(keys, KW)
     ref = None
-    for backend, layout in COMBOS:
+    for backend in BACKENDS:
         v, r = B.lookup_batch(t, allk.bytes, allk.lens,
-                              engine=TraversalEngine(backend, layout))
+                              engine=TraversalEngine(backend))
         sig = (np.asarray(v), np.asarray(r.found))
         if ref is None:
             ref = sig
             expect = np.array([i % 4 != 0 for i in range(len(keys))])
             assert (sig[1] == expect).all()
-        assert (sig[0] == ref[0]).all() and (sig[1] == ref[1]).all(), \
-            (backend, layout)
+        assert (sig[0] == ref[0]).all() and (sig[1] == ref[1]).all(), backend
 
 
 @settings(deadline=None, max_examples=6,
@@ -197,9 +186,8 @@ def test_rebuild_preserves_engine_parity():
        st.integers(0, 2**31 - 1))
 def test_stats_free_path_bit_identical(ds_name, seed):
     """collect_stats=False is observationally identical on leaf ids and
-    per-level paths for EVERY engine — level and descent backends alike —
-    and returns all-zero counters (the stats machinery compiles away,
-    DESIGN.md §3)."""
+    per-level paths for EVERY level backend, and returns all-zero counters
+    (the stats machinery compiles away, DESIGN.md §3)."""
     tree, ks = _build(ds_name, 500, seed % 1000)
     rng = np.random.default_rng(seed)
     idx = rng.integers(0, ks.n, size=160)
@@ -209,58 +197,47 @@ def test_stats_free_path_bit_identical(ds_name, seed):
     qb[flip, -1] ^= 0xA5
     qb, ql = jnp.asarray(qb), jnp.asarray(ql)
 
-    all_combos = COMBOS + [("binary", "tuple"), ("binary+prefix", "stacked")]
-    for backend, layout in all_combos:
-        on = TraversalEngine(backend, layout, collect_stats=True)
-        off = TraversalEngine(backend, layout, collect_stats=False)
+    for backend in LEVEL_BACKENDS:
+        on = TraversalEngine(backend, collect_stats=True)
+        off = TraversalEngine(backend, collect_stats=False)
         leaf_on, path_on, _ = on.traverse(tree, qb, ql)
         leaf_off, path_off, stats_off = off.traverse(tree, qb, ql)
         assert (np.asarray(leaf_off) == np.asarray(leaf_on)).all(), \
-            (backend, layout, "leaf ids")
+            (backend, "leaf ids")
         for lvl, (p, rp) in enumerate(zip(path_off, path_on)):
             assert (np.asarray(p) == np.asarray(rp)).all(), \
-                (backend, layout, "path at level", lvl)
+                (backend, "path at level", lvl)
         for f in stats_off._fields:
             assert (np.asarray(getattr(stats_off, f)) == 0).all(), \
-                (backend, layout, f)
+                (backend, f)
 
 
 def test_stats_free_lookup_matches():
-    """The full op pipeline (descend + probe, fused or not) returns the
-    same values/found under a stats-free engine; counters are zero."""
+    """The full op pipeline (descend + probe) returns the same
+    values/found under a stats-free engine; counters are zero."""
     tree, ks = _build("ycsb", 500, 3)
     qb = jnp.asarray(ks.bytes[:128])
     ql = jnp.asarray(ks.lens[:128])
     v_ref, r_ref = B.lookup_batch(tree, qb, ql,
-                                  engine=TraversalEngine("jnp", "tuple"))
-    for backend, layout in COMBOS:
-        eng = TraversalEngine(backend, layout, collect_stats=False)
+                                  engine=TraversalEngine("jnp"))
+    for backend in BACKENDS:
+        eng = TraversalEngine(backend, collect_stats=False)
         v, r = B.lookup_batch(tree, qb, ql, engine=eng)
-        assert (np.asarray(v) == np.asarray(v_ref)).all(), (backend, layout)
+        assert (np.asarray(v) == np.asarray(v_ref)).all(), backend
         assert (np.asarray(r.found) == np.asarray(r_ref.found)).all()
         for f in ("feat_rounds", "suffix_bs", "key_compares",
                   "lines_touched", "tag_candidates"):
-            assert (np.asarray(getattr(r, f)) == 0).all(), (backend, layout, f)
+            assert (np.asarray(getattr(r, f)) == 0).all(), (backend, f)
 
 
 def test_backend_registry():
-    for name in ("jnp", "pallas", "binary", "binary+prefix"):
-        assert name in available_backends()
-        assert backend_kind(name) == "level"
+    """One registry: the four level backends, nothing else."""
+    assert available_backends() == sorted(LEVEL_BACKENDS)
+    for name in LEVEL_BACKENDS:
         assert callable(get_backend(name))
-    assert "fused" in available_backends()
-    assert backend_kind("fused") == "descent"
-    d = get_descent_backend("fused")
-    assert callable(d.traverse) and callable(d.traverse_probe)
     with pytest.raises(KeyError):
         get_backend("no-such-backend")
-    with pytest.raises(KeyError):
-        get_descent_backend("no-such-backend")
     with pytest.raises(ValueError):
         TraversalEngine(backend="no-such-backend")
     assert DEFAULT_ENGINE.backend == "jnp"
     assert DEFAULT_ENGINE.collect_stats
-    # descent engines expose the fused traverse+probe hook; level engines
-    # don't (batch_ops collapses to one launch only for the former)
-    assert TraversalEngine("fused").probe_path() is not None
-    assert TraversalEngine("jnp").probe_path() is None
