@@ -116,8 +116,7 @@ def main(argv=None):
     report = []     # per-suite timing/status -> out/bench_report.json
     for name in names:
         title, fn, cols = SUITES[name]
-        eng = (dict(backend=args.backend)
-               if name in _ENGINE_SUITES else {})
+        eng = dict(backend=args.backend) if name in _ENGINE_SUITES else {}
         if args.smoke and name in ("traverse", "scan", "shard"):
             eng["smoke"] = True
         t0 = time.time()

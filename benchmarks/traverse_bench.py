@@ -18,9 +18,8 @@ build) so the perf trajectory of future kernel PRs starts here.
 
 ``smoke=True`` is the CI mode (`benchmarks/run.py --suite traverse
 --smoke`): tiny trees, one timing iteration, every backend, ``pallas`` in
-interpret mode — the parity asserts are the point; a
-kernel-path regression fails CI instead of rotting until the next bench
-run.
+interpret mode — the parity asserts are the point; a kernel-path
+regression fails CI instead of rotting until the next bench run.
 """
 from __future__ import annotations
 
@@ -59,8 +58,7 @@ def run(datasets=("ycsb", "url"), n_keys=20_000, n_ops=16_384,
         ref_found = None
         for backend in BACKENDS:
             for stats_on in (True, False):
-                eng = TraversalEngine(backend=backend,
-                                      collect_stats=stats_on)
+                eng = TraversalEngine(backend=backend, collect_stats=stats_on)
                 def fn():
                     outs = []
                     for off in range(0, n_ops, chunk):

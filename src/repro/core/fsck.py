@@ -4,8 +4,8 @@
 on a *simulated* tree; this module ports the same invariants — chain
 order, high-key coverage, accounting — to the actual device arrays, plus
 everything the structure-of-arrays layout adds (anchor order, DFS
-reachability, meta coherence). It is the
-gate :class:`core.lifecycle.TreeVersionManager` runs on every staged tree
+reachability, meta coherence). It is the gate
+:class:`core.lifecycle.TreeVersionManager` runs on every staged tree
 before a publish swap: a corrupted or half-built version can never become
 the serving version.
 

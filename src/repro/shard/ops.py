@@ -7,10 +7,10 @@ then launches ONE jitted shard-local op per shard that owns work — the
 same ``core.batch_ops`` entry points every unsharded call site uses,
 through the same ``TraversalEngine`` (any level backend). Launches are
 asynchronous per device, so with a multi-device mesh the shards
-genuinely overlap. A
-lookup's results are copied device to device to shard 0's device and
-selected by owner there, in one small jitted program, and returned as
-device arrays whose host copies are already under way: for a host batch
+genuinely overlap. A lookup's results are copied device to device to
+shard 0's device and selected by owner there, in one small jitted
+program, and returned as device arrays whose host copies are already
+under way: for a host batch
 the call never waits on a device, so the caller's next work overlaps the
 shard lookups. Mutations and scans combine their reports on the host.
 

@@ -69,8 +69,9 @@ def _expected(tree, qb_row, ql_row, max_items):
 @given(st.sampled_from(("rand-int", "ycsb", "url")), st.booleans(),
        st.integers(0, 2**31 - 1))
 def test_scan_backend_parity(ds_name, dirty, seed):
-    """jnp and pallas descents, ordered and dirty trees: identical pairs, ascending, starting at the first key >= query,
-    EMPTY past ``emitted``; ``rearranged`` agrees across backends."""
+    """jnp and pallas descents, ordered and dirty trees: identical pairs,
+    ascending, starting at the first key >= query, EMPTY past ``emitted``;
+    ``rearranged`` agrees across backends."""
     tree, width = _build_churned(ds_name, 400, seed % 1000, dirty=dirty)
     a = tree.arrays
     kid_s, kb_s, kl_s, _ = _oracle(tree)
